@@ -132,7 +132,8 @@ def run_child(mode: str, staged: str, k: int, partitioner: str,
         raise ValueError(mode)
     wall = time.perf_counter() - t0
     rec = {
-        "mode": mode, "wall_s": round(wall, 3),
+        "mode": mode, "backend": jax.default_backend(),
+        "wall_s": round(wall, 3),
         "shape": graph.shape_summary,
         "pad_waste": round(float(graph.pad_waste), 3),
         "digest": graph_digest(graph),
@@ -161,6 +162,9 @@ def _spawn(mode: str, staged: str, k: int, partitioner: str,
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(REPO_ROOT, "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
+    # builds measure host RSS: pinned to the CPU, so a child never competes
+    # for an accelerator (which belongs to one process at a time)
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.ingest_bench", "--child", mode,
            "--staged", staged, "--k", str(k), "--partitioner", partitioner,
            "--chunk-edges", str(chunk_edges), "--n", str(n),
@@ -178,10 +182,8 @@ def _spawn(mode: str, staged: str, k: int, partitioner: str,
 
 def bench_ingest(out_path: str = DEFAULT_OUT, fast: bool = False,
                  chunk_edges: int = 1 << 20) -> dict:
-    import jax
-
-    results: dict = {"meta": {"backend": jax.default_backend(),
-                              "n_partitions": N_PARTITIONS,
+    # the parent stays off JAX; the backend is the children's (see _spawn)
+    results: dict = {"meta": {"n_partitions": N_PARTITIONS,
                               "avg_degree": AVG_DEGREE,
                               "chunk_edges": chunk_edges,
                               "fast": bool(fast),
@@ -207,6 +209,7 @@ def bench_ingest(out_path: str = DEFAULT_OUT, fast: bool = False,
             for mode in ("inmem", "ooc"):
                 child = _spawn(mode, staged, N_PARTITIONS, partitioner,
                                build_ell, chunk_edges)
+                results["meta"]["backend"] = child.pop("backend")
                 rec[mode] = {k: v for k, v in child.items() if k != "mode"}
                 print(f"{name}/{mode}: wall {child['wall_s']}s, "
                       f"peak rss +{child['peak_rss_mb']}MB "

@@ -13,8 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from benchmarks.roofline import PEAK_FLOPS
-
 ARCH_ORDER = [
     "graphhp-paper",
 ]

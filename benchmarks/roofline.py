@@ -4,6 +4,8 @@ compute term    = HLO_FLOPs / peak_FLOPs          (per chip, cost_analysis)
 memory term     = HLO_bytes / HBM_bw              (per chip, cost_analysis)
 collective term = collective_bytes / ICI_bw       (per chip, parsed from HLO)
 
+with the peaks of the target ``device_kind`` from :data:`HARDWARE`.
+
 collective_bytes is NOT in cost_analysis: we parse the compiled module text
 and sum wire bytes of every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute, with op-specific ring-cost factors.
@@ -13,9 +15,25 @@ from __future__ import annotations
 
 import re
 
-PEAK_FLOPS = 197e12          # bf16 per chip (TPU v5e)
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind`` — the one
+#: source of hardware constants in the repo.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of chip-to-chip interconnect).
+HARDWARE = {
+    "TPU v5 lite": {"peak_flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 1600e9 / 8},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; an unknown device raises."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}: add it to HARDWARE with its "
+                       f"source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -101,13 +119,14 @@ def _group_size(line: str) -> int:
     return 1
 
 
-def roofline_terms(cost: dict, hlo_text: str) -> dict:
+def roofline_terms(cost: dict, hlo_text: str, device_kind: str) -> dict:
+    hw = peaks(device_kind)
     flops = float(cost.get("flops", 0.0) or 0.0)
     bytes_hbm = float(cost.get("bytes accessed", 0.0) or 0.0)
     coll = collective_bytes(hlo_text)
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_hbm / HBM_BW
-    t_coll = coll["total"] / ICI_BW
+    t_compute = flops / hw["peak_flops_bf16"]
+    t_memory = bytes_hbm / hw["hbm_bytes_per_s"]
+    t_coll = coll["total"] / hw["ici_bytes_per_s"]
     dominant = max((("compute", t_compute), ("memory", t_memory),
                     ("collective", t_coll)), key=lambda kv: kv[1])[0]
     return {

@@ -92,24 +92,14 @@ def make_dist_hybrid_step(prog: VertexProgram, mesh: Mesh,
                 f"count ({d}); build with edge_blocks={d} (or a multiple)")
         in_specs = (shard0_specs(graph, axes), _es_specs(es, axes))
         out_specs = _es_specs(es, axes)
-        return _shard_map(local_step, mesh, in_specs, out_specs)(graph, es)
+        return jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(graph, es)
 
     if tracer is not None:
         from repro.obs.trace import traced_dist_step   # lazy: opt-in only
         return traced_dist_step(step, tracer, mesh.size,
                                 wire_dtype=wire_dtype)
     return step
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions (older
-    releases ship it under jax.experimental with a ``check_rep`` kwarg)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
 
 
 def _es_specs(es: EngineState, axes) -> Any:

@@ -349,12 +349,12 @@ def build_partitioned_graph(
 
     ``build_ell`` additionally packs each partition's local *and* remote
     in-edges into destination-major sliced-ELL layouts (the kernel fast
-    paths for both delivery phases).  ``ell_pad_slices`` pads the slice axis
-    (use 128 when targeting TPU lanes; 8 keeps CPU/interpret memory small).
-    ``ell_base_slices`` bounds the dense base bin: rows whose in-degree
-    exceeds it spill into up to two extra degree bins (see
+    paths for both delivery phases).  ``ell_pad_slices`` pads the slice
+    axis; the kernels re-tile every bin slot-major, so no TPU alignment is
+    needed here.  ``ell_base_slices`` bounds the dense base bin: rows whose
+    in-degree exceeds it spill into geometrically wider degree bins (see
     ``kernels.common.ell_bin_widths``), so power-law skew widens only the
-    tiny spill bins instead of padding every row to the hub degree.
+    small spill bins instead of padding every row to the hub degree.
 
     For graphs too large to hold as one in-memory edge array, the same
     structure — bit-identical — is produced out-of-core by
@@ -410,11 +410,10 @@ def build_partitioned_graph(
     halo_by_p = [np.unique(src[cross & (pdst == p)]) for p in range(P)]
 
     # --- exporters: vertices with >= 1 crossing out-edge ------------------
-    exp_pairs = np.unique(
-        np.stack([src[cross], pdst[cross].astype(np.int64)], axis=1), axis=0
-    )
+    # sorted unique (src, dst-partition) pairs, via one int64 key
+    exp_key = np.unique(src[cross] * P + pdst[cross])
     exporters_by_p, fanout_by_p, export_idx_of = _export_tables(
-        exp_pairs[:, 0], part, n_vertices, P)
+        exp_key // P, part, n_vertices, P)
     X = _round_up(max((len(v) for v in exporters_by_p), default=1), pad_multiple)
     H = _round_up(max((len(h) for h in halo_by_p), default=1), pad_multiple)
 

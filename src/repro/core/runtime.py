@@ -256,7 +256,7 @@ _SCATTER = {
 }
 
 
-def ell_combine_bins(prog, ch, slices, views, x, y, p: int, interpret: bool):
+def ell_combine_bins(prog, ch, slices, views, x, y, p: int):
     """⊕-combine each bin's ``ell_spmv`` partials onto the flat destination
     vector ``y`` — the dense base bin via the semiring combine, spill bins
     via semiring scatter over their row lists.  The single source of truth
@@ -267,8 +267,7 @@ def ell_combine_bins(prog, ch, slices, views, x, y, p: int, interpret: bool):
     combine, _, _ = SEMIRINGS[ch.semiring]
     for s, (rows, idx, msk) in zip(slices, views):
         v = prog.ell_edge_values(ch, s.val).reshape(-1, s.kb)
-        yb = ell_spmv(idx, v, msk, x, semiring=ch.semiring,
-                      interpret=interpret)
+        yb = ell_spmv(idx, v, msk, x, semiring=ch.semiring)
         if s.dense:
             y = combine(y, yb)
         else:
@@ -332,7 +331,7 @@ def _ell_deliver(graph, prog, chs, es, pending, delivered, collect_metrics,
     (and, when ``collect_metrics``, the paper counters) come from a cheap
     masked gather of the send flags through the same layout.
     """
-    from repro.kernels.common import SEMIRINGS, default_interpret
+    from repro.kernels.common import SEMIRINGS
 
     p, vp = es.send.shape
     slices = ell_slices(graph, edges)
@@ -343,7 +342,6 @@ def _ell_deliver(graph, prog, chs, es, pending, delivered, collect_metrics,
         out_tab = jax.tree.map(cat, es.out, es.halo_out)
         send_tab = cat(es.send, es.halo_send)
     send_flat = send_tab.reshape(-1)
-    interpret = default_interpret()
 
     # has-message flags per destination, shared by every kernel channel
     views = [slice_flat(s, graph, p) for s in slices]
@@ -361,7 +359,7 @@ def _ell_deliver(graph, prog, chs, es, pending, delivered, collect_metrics,
         # dispatch (semiring SpMM): flatten partitions only, keep lanes
         x = x.reshape((-1,) + x.shape[2:]).astype(jnp.float32)
         y = jnp.full((p * vp,) + x.shape[1:], ident, jnp.float32)
-        y = ell_combine_bins(prog, ch, slices, views, x, y, p, interpret)
+        y = ell_combine_bins(prog, ch, slices, views, x, y, p)
         y = y.reshape((p, vp) + y.shape[1:])
         dt, ident_ch = ch.components[0]
         has_b = has_fresh.reshape(

@@ -109,9 +109,12 @@ def run_engine(
     """
     if es is None:
         es = policy.init(graph, prog, vdata)
-    if jit_step is None:
-        jit_step = jax.jit(
-            lambda e: policy.step(graph, prog, e, vdata))
+    own_step = jit_step is None
+    if own_step:
+        # the graph and vdata are jit *arguments*: closed over, every graph
+        # array would be baked into the program as a constant
+        step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
+        jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
 
     ctx = ExecContext(graph=graph, prog=prog, policy=policy, vdata=vdata,
                       es=es, iteration=int(es.counters.iterations))
@@ -127,8 +130,13 @@ def run_engine(
                 f"device_loop=True runs with no host boundary between "
                 f"steps; hooks {[type(h).__name__ for h in stepwise]} "
                 f"override before_step/after_step and need the host loop")
-        ctx.es = jax.jit(
-            lambda e: while_engine(prog, jit_step, e, max_iters))(ctx.es)
+        if own_step:
+            ctx.es = jax.jit(lambda g, v, e: while_engine(
+                prog, lambda e_: policy.step(g, prog, e_, v), e,
+                max_iters))(graph, vdata, ctx.es)
+        else:
+            ctx.es = jax.jit(
+                lambda e: while_engine(prog, jit_step, e, max_iters))(ctx.es)
         ctx.iteration = int(ctx.es.counters.iterations)
     else:
         while (ctx.iteration < max_iters

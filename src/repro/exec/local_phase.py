@@ -74,7 +74,7 @@ def fused_local_kernel(graph: PartitionedGraph, prog: VertexProgram,
 
 
 def _spill_extra(graph: PartitionedGraph, prog, ch, slices, views, out_d,
-                 send, p, interpret):
+                 send, p):
     """⊕-combined spill-bin contributions (P*Vp, ...) for a fused kernel's
     ``extra`` operand — None when the layout is a single dense bin.  Lane
     channels keep their trailing (L,) axis through the spill SpMM."""
@@ -87,8 +87,7 @@ def _spill_extra(graph: PartitionedGraph, prog, ch, slices, views, out_d,
     x = prog.ell_payload(ch, out_d, send)
     x = x.reshape((-1,) + x.shape[2:]).astype(jnp.float32)
     extra = jnp.full((p * graph.vp,) + x.shape[1:], ident, jnp.float32)
-    return ell_combine_bins(prog, ch, slices[1:], views[1:], x, extra, p,
-                            interpret)
+    return ell_combine_bins(prog, ch, slices[1:], views[1:], x, extra, p)
 
 
 def fused_step_fn(graph: PartitionedGraph, prog: VertexProgram, kind: str,
@@ -105,14 +104,12 @@ def fused_step_fn(graph: PartitionedGraph, prog: VertexProgram, kind: str,
     :func:`_spill_extra`.
     """
     from repro.core.runtime import slice_flat
-    from repro.kernels.common import default_interpret
 
     ch = prog.channels[0]
     vp = graph.vp
     slices = graph.local_ell
     views = [slice_flat(s, graph, p) for s in slices]
     _, idx, msk = views[0]
-    interpret = default_interpret()
     flat = lambda a: a.reshape((-1,) + a.shape[2:])
     unflat = lambda a: a.reshape((p, vp) + a.shape[1:])
 
@@ -123,11 +120,10 @@ def fused_step_fn(graph: PartitionedGraph, prog: VertexProgram, kind: str,
 
         def step(rank, delta, send):
             extra = _spill_extra(graph, prog, ch, slices, views,
-                                 {ch.name: delta}, send, p, interpret)
+                                 {ch.name: delta}, send, p)
             r, d, s = fused_pr_step(
                 idx, val, msk, flat(delta), flat(send),
-                flat(rank), extra, damping=prog.damping, tol=prog.tol,
-                interpret=interpret)
+                flat(rank), extra, damping=prog.damping, tol=prog.tol)
             return unflat(r), unflat(d), unflat(s)
     elif kind == "min_step":
         from repro.kernels.min_step import fused_min_step
@@ -137,10 +133,10 @@ def fused_step_fn(graph: PartitionedGraph, prog: VertexProgram, kind: str,
 
         def step(x, send):
             extra = _spill_extra(graph, prog, ch, slices, views,
-                                 {ch.name: x}, send, p, interpret)
+                                 {ch.name: x}, send, p)
             xn, d, s = fused_min_step(
                 idx, val, msk, flat(x), flat(send), extra=extra,
-                semiring=ch.semiring, interpret=interpret)
+                semiring=ch.semiring)
             return unflat(xn), unflat(d), unflat(s)
     else:  # pragma: no cover
         raise ValueError(kind)

@@ -22,17 +22,16 @@ The frontier ``x`` is either a vector (N,) — the classic SpMV — or a stacked
 frontier *matrix* (N, L) of L independent query lanes (multi-source SSSP,
 landmark tables, per-seed personalized PageRank), in which case the same
 gather indices serve every lane and the product/reduce broadcast over the
-trailing lane axis: one dispatch computes a semiring SpMM, y (R, L).  The
-1-D path is untouched — lane handling is a static rank check, so single-lane
-callers compile the exact original kernel.
+trailing lane axis: one dispatch computes a semiring SpMM, y (R, L).  A
+vector frontier is the one-lane case of the same kernel, so a lane column
+is bit-identical to the single-frontier dispatch of that lane.
 
-Blocking: grid = (R/Bm, K/Bk); each step loads a (Bm, Bk) tile of idx/val/msk
-into VMEM plus the whole source vector x (a graph partition's frontier fits
-VMEM comfortably: 64k fp32 slots = 256 KiB), gathers, reduces over the slice
-axis and accumulates into the (Bm,) output block across the K-grid dimension —
-the standard TPU revisiting-output-block accumulation pattern.  Row blocks are
-multiples of 8 and slice blocks multiples of 128 so tiles are VPU
-lane/sublane aligned.
+Blocking: the frontier gather runs in XLA (Mosaic cannot gather from a
+frontier inside a kernel), producing the slot-major tile of
+`kernels.common`; the kernel applies the masked ⊗ and folds the slots of
+each (bk, 8, bc) block, in slot order, into an (8, bc)
+output block revisited along the K grid axis.  Nothing frontier-sized
+enters VMEM, so the frontier's size and lane count are not bounded by it.
 """
 
 from __future__ import annotations
@@ -41,31 +40,21 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.common import SEMIRINGS, accumulate_k, ell_blocking
+from repro.kernels.common import (SEMIRINGS, fold_slots, gather_lanes,
+                                  map_lanes, segment_count, slot_fold_call,
+                                  slot_tiles, tiles_to_rows)
 
 
-def _kernel(idx_ref, val_ref, msk_ref, x_ref, y_ref, *, semiring: str):
+def _kernel(g_ref, val_ref, msk_ref, y_ref, *, semiring: str):
     combine, times, ident = SEMIRINGS[semiring]
+    ident = jnp.asarray(ident, y_ref.dtype)
 
-    idx = idx_ref[...]                      # (Bm, Bk) int32
-    val = val_ref[...]                      # (Bm, Bk)
-    msk = msk_ref[...]                      # (Bm, Bk)
-    x = x_ref[...]                          # (N,) or (N, L) — whole frontier
+    def slot(j):
+        prod = times(val_ref[j], g_ref[j])
+        return jnp.where(msk_ref[j] != 0, prod, ident)
 
-    if x.ndim == 2:                         # K-lane SpMM: broadcast the edge
-        val = val[..., None]                # tile over the trailing lane axis
-        msk = msk[..., None]
-    gathered = x[idx]                       # (Bm, Bk) or (Bm, Bk, L)
-    prod = times(val, gathered)
-    prod = jnp.where(msk, prod, jnp.asarray(ident, prod.dtype))
-
-    partial = prod[:, 0]
-    for j in range(1, prod.shape[1]):       # slice-axis tree would also do;
-        partial = combine(partial, prod[:, j])   # XLA re-associates on VPU
-
-    accumulate_k(y_ref, partial, combine)
+    fold_slots(y_ref, g_ref.shape[0], slot, combine, ident)
 
 
 def ell_spmv_pallas(
@@ -75,28 +64,36 @@ def ell_spmv_pallas(
     x: jax.Array,
     *,
     semiring: str = "add_mul",
-    block_rows: int = 256,
-    block_slices: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """y = ⊕_k val ⊗ x[idx] per row.  Returns (R,) for an (N,) frontier and
-    (R, L) for a stacked (N, L) lane frontier, in x.dtype."""
-    r, kk = idx.shape
-    bm, bk, _, grid = ell_blocking(r, kk, block_rows, block_slices)
-    lanes = x.shape[1:]                     # () SpMV or (L,) SpMM
+    (R, L) for a stacked (N, L) lane frontier, in x.dtype.  ``interpret``
+    defaults to the platform's (``kernels.common.default_interpret``)."""
+    r, k = idx.shape
+    seg = segment_count(r, k)
+    if seg > 1:                             # a hub bin: fold row segments
+        ks = -(-k // seg)
+        cut = lambda a: jnp.pad(a, ((0, 0), (0, seg * ks - k))).reshape(
+            r * seg, ks)
+        idx, val, msk = cut(idx), cut(val), cut(msk)
+    tiles = slot_tiles(idx, val, msk)
 
-    return pl.pallas_call(
-        functools.partial(_kernel, semiring=semiring),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec(x.shape, lambda i, k: (0,) * x.ndim),
-        ],
-        out_specs=pl.BlockSpec((bm,) + lanes,
-                               (lambda i, k: (i, 0)) if lanes
-                               else (lambda i, k: (i,))),
-        out_shape=jax.ShapeDtypeStruct((r,) + lanes, x.dtype),
-        interpret=interpret,
-    )(idx, val, msk, x)
+    def call(x):
+        lane_shape = x.shape[1:]            # () SpMV or (L,) SpMM
+        (y,) = slot_fold_call(
+            functools.partial(_kernel, semiring=semiring), tiles,
+            lane_shape[0] if lane_shape else 1,
+            lane_edge=(gather_lanes(x, tiles),),
+            shared_edge=(tiles.val, tiles.msk),
+            out_dtypes=(x.dtype,), interpret=interpret)
+        return (tiles_to_rows(y, tiles, lane_shape),)
+
+    (y,) = map_lanes(call, (x,), tiles)
+    lane_shape = x.shape[1:]
+    if seg > 1:                             # ⊕ the segments in slot order
+        y = y.reshape((r, seg) + lane_shape)
+        acc = y[:, 0]
+        for s in range(1, seg):
+            acc = SEMIRINGS[semiring][0](acc, y[:, s])
+        y = acc
+    return y

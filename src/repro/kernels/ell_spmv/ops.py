@@ -13,23 +13,18 @@ from repro.kernels.common import ell_pack_numpy
 from repro.kernels.ell_spmv.ell_spmv import ell_spmv_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("semiring", "block_rows",
-                                             "block_slices", "interpret"))
-def ell_spmv(idx, val, msk, x, *, semiring: str = "add_mul",
-             block_rows: int = 256, block_slices: int = 128,
-             interpret: bool = True) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("semiring",))
+def ell_spmv(idx, val, msk, x, *, semiring: str = "add_mul") -> jax.Array:
     """Jitted semiring SpMV/SpMM: y[r] = ⊕_k val[r,k] ⊗ x[idx[r,k]].
 
     ``x`` is an (N,) frontier vector (SpMV, returns (R,)) or an (N, L)
     stacked frontier of L query lanes (semiring SpMM, returns (R, L) — one
     dispatch answers L simultaneous sources over the same edge tiles).
 
-    ``interpret=True`` executes the Pallas kernel body on CPU (this
-    container); on a TPU runtime pass ``interpret=False`` to lower to Mosaic.
+    The kernel lowers to Mosaic on a TPU and runs in interpret mode
+    elsewhere (``kernels.common.default_interpret``).
     """
-    return ell_spmv_pallas(idx, val, msk, x, semiring=semiring,
-                           block_rows=block_rows, block_slices=block_slices,
-                           interpret=interpret)
+    return ell_spmv_pallas(idx, val, msk, x, semiring=semiring)
 
 
 def to_ell(edges: np.ndarray, n_rows: int,

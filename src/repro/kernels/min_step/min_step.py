@@ -20,9 +20,11 @@ pseudo-superstep — the monotone twin of `pr_step`.
 ``extra`` carries spill-bin contributions of the sliced-ELL layout (the
 ⊕-partials of the high-degree rows' overflow slots, pre-combined outside)
 and is folded in during the epilogue, so degree-binned power-law graphs fuse
-exactly like single-bin graphs.  Same blocking scheme as `ell_spmv`: grid
-(R/Bm, K/Bk), (Bm, Bk) edge tiles, frontier vectors whole in VMEM, output
-accumulated across the K grid axis with the epilogue on the final K step.
+exactly like single-bin graphs.  Same blocking scheme as `ell_spmv`: the
+senders' values are gathered in XLA into slot-major tiles (`kernels.common`)
+— with a per-lane sender gate beside them where the ⊕-identity does not
+absorb ⊗ — and the kernel folds the slots across the K grid axis and runs
+the epilogue on the final K step.
 """
 
 from __future__ import annotations
@@ -33,83 +35,72 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (MONOTONE_SEMIRINGS, SEMIRINGS, accumulate_k,
-                                  ell_blocking, semiring_improves)
+from repro.kernels.common import (MONOTONE_SEMIRINGS, SEMIRINGS, fold_slots,
+                                  gather_lanes, map_lanes, rows_to_tiles,
+                                  semiring_improves, slot_fold_call,
+                                  slot_tiles, tiles_to_rows)
 
 
-def _kernel(idx_ref, val_ref, msk_ref, x_ref, send_ref, xrow_ref, extra_ref,
-            acc_ref, x_out_ref, send_out_ref, *, n_kblocks: int,
-            semiring: str):
+# Semirings whose ⊕-identity absorbs ⊗ with any finite edge value
+# (inf + v = inf, -inf + v = -inf, min(-inf, v) = -inf): a non-sender can
+# ride the gather as the identity and needs no per-lane sender gate.
+# min_mul does not (inf * 0 is nan), so it keeps the gate.
+_IDENT_ABSORBS = frozenset({"min_add", "max_add", "max_min"})
+
+
+def _kernel(g_ref, m_ref, val_ref, xrow_ref, extra_ref, acc_ref, x_out_ref,
+            send_out_ref, *, semiring: str):
     combine, times, ident = SEMIRINGS[semiring]
     improves = semiring_improves(semiring)
-    k = pl.program_id(1)
+    ident = jnp.asarray(ident, acc_ref.dtype)
 
-    idx = idx_ref[...]
-    val = val_ref[...]
-    msk = msk_ref[...]
-    x = x_ref[...]                          # (N,) or (N, L) lane frontier
-    send = send_ref[...]                    # matches x's rank (per-lane send)
+    def slot(j):
+        cand = times(g_ref[j], val_ref[j])
+        return jnp.where(m_ref[j] != 0, cand, ident)
 
-    if x.ndim == 2:                         # K-lane SpMM: edge tile broadcast
-        val = val[..., None]                # over the trailing lane axis
-        msk = msk[..., None]
-    cand = times(x[idx], val)
-    cand = jnp.where(jnp.logical_and(msk, send[idx]),
-                     cand, jnp.asarray(ident, cand.dtype))
+    acc = fold_slots(acc_ref, g_ref.shape[0], slot, combine, ident)
 
-    partial = cand[:, 0]
-    for j in range(1, cand.shape[1]):       # slice-axis fold, as in ell_spmv
-        partial = combine(partial, cand[:, j])
-
-    accumulate_k(acc_ref, partial, combine)
-
-    @pl.when(k == n_kblocks - 1)
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _epilogue():
-        d_in = combine(acc_ref[...], extra_ref[...])
+        d_in = combine(acc, extra_ref[...])
         acc_ref[...] = d_in
         xr = xrow_ref[...]
         x_out_ref[...] = combine(xr, d_in)
-        send_out_ref[...] = improves(d_in, xr)
+        send_out_ref[...] = improves(d_in, xr).astype(send_out_ref.dtype)
 
 
 def fused_min_step_pallas(idx, val, msk, x, send, xrow, extra, *,
                           semiring: str = "min_add",
-                          block_rows: int = 256, block_slices: int = 128,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """-> (x', d_in, send').  ``x`` is the (N,) frontier — or (N, L) for L
     independent query lanes, in which case ``send``/``xrow``/``extra`` carry
     the same trailing lane axis and all three outputs are (R, L).  ``xrow``
     is the per-row state the epilogue compares against (the same array when
     rows and frontier share the vertex slot space), ``extra`` a pre-combined
     spill contribution (the ⊕-identity where none)."""
-    assert semiring in MONOTONE_SEMIRINGS, semiring
-    r, kk = idx.shape
-    bm, bk, nkb, grid = ell_blocking(r, kk, block_rows, block_slices)
-    lanes = x.shape[1:]                     # () SpMV or (L,) lane SpMM
+    if semiring not in MONOTONE_SEMIRINGS:
+        raise ValueError(f"{semiring} is not a monotone semiring")
+    tiles = slot_tiles(idx, val, msk)
 
-    front_spec = pl.BlockSpec(x.shape, lambda i, k: (0,) * x.ndim)
-    row_spec = pl.BlockSpec((bm,) + lanes,
-                            (lambda i, k: (i, 0)) if lanes
-                            else (lambda i, k: (i,)))
+    def call(x, send, xrow, extra):
+        lane_shape = x.shape[1:]            # () SpMV or (L,) lane SpMM
+        if semiring in _IDENT_ABSORBS:      # one gather: senders' values
+            ident = jnp.asarray(SEMIRINGS[semiring][2], x.dtype)
+            edge = dict(lane_edge=(gather_lanes(jnp.where(send, x, ident),
+                                                tiles),),
+                        shared_edge=(tiles.msk, tiles.val))
+        else:                               # values and per-lane int32 gate
+            gate = gather_lanes(send, tiles,
+                                lambda s: tiles.msk * s.astype(jnp.int32))
+            edge = dict(lane_edge=(gather_lanes(x, tiles), gate),
+                        shared_edge=(tiles.val,))
+        acc, x_out, send_out = slot_fold_call(
+            functools.partial(_kernel, semiring=semiring), tiles,
+            lane_shape[0] if lane_shape else 1, **edge,
+            row_ops=(rows_to_tiles(xrow, tiles), rows_to_tiles(extra, tiles)),
+            out_dtypes=(x.dtype, x.dtype, jnp.int32), interpret=interpret)
+        back = lambda t: tiles_to_rows(t, tiles, lane_shape)
+        return back(x_out), back(acc), back(send_out) != 0
 
-    acc, x_out, send_out = pl.pallas_call(
-        functools.partial(_kernel, n_kblocks=nkb, semiring=semiring),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            front_spec,
-            front_spec,
-            row_spec,
-            row_spec,
-        ],
-        out_specs=[row_spec, row_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((r,) + lanes, x.dtype),
-            jax.ShapeDtypeStruct((r,) + lanes, x.dtype),
-            jax.ShapeDtypeStruct((r,) + lanes, jnp.bool_),
-        ],
-        interpret=interpret,
-    )(idx, val, msk, x, send, xrow, extra)
-    return x_out, acc, send_out
+    return map_lanes(call, (x, send, xrow.astype(x.dtype),
+                            extra.astype(x.dtype)), tiles)

@@ -11,12 +11,9 @@ from repro.kernels.common import SEMIRINGS
 from repro.kernels.min_step.min_step import fused_min_step_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("semiring", "block_rows",
-                                             "block_slices", "interpret"))
+@functools.partial(jax.jit, static_argnames=("semiring",))
 def fused_min_step(idx, val, msk, x, send, xrow=None, extra=None, *,
-                   semiring: str = "min_add",
-                   block_rows: int = 256, block_slices: int = 128,
-                   interpret: bool = True):
+                   semiring: str = "min_add"):
     """Jitted fused monotone pseudo-superstep -> (x', d_in, send').
 
     ``semiring`` is any ``MONOTONE_SEMIRINGS`` entry (default the historic
@@ -31,6 +28,4 @@ def fused_min_step(idx, val, msk, x, send, xrow=None, extra=None, *,
         _, _, ident = SEMIRINGS[semiring]
         extra = jnp.full(idx.shape[:1] + x.shape[1:], ident, x.dtype)
     return fused_min_step_pallas(idx, val, msk, x, send, xrow, extra,
-                                 semiring=semiring, block_rows=block_rows,
-                                 block_slices=block_slices,
-                                 interpret=interpret)
+                                 semiring=semiring)

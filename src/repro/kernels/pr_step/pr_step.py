@@ -10,9 +10,10 @@ The unfused engine path runs gather → segment-sum → add → compare as four 
 ops with HBM round-trips between them; since the local phase iterates this
 to convergence (the paper's whole point is that it iterates *a lot*), fusing
 the chain into one VMEM-resident kernel removes three HBM round-trips per
-pseudo-superstep.  Same blocking scheme as ell_spmv: grid (R/Bm, K/Bk),
-(Bm, Bk) edge tiles, frontier vectors whole in VMEM, output accumulated
-across the K grid axis with the epilogue on the final K step.
+pseudo-superstep.  Same blocking scheme as ell_spmv: the senders' deltas
+are gathered in XLA into slot-major tiles (`kernels.common`), the kernel
+sums the slots in slot order across the K grid axis and runs the epilogue
+on the final K step.
 """
 
 from __future__ import annotations
@@ -23,77 +24,53 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import accumulate_k, ell_blocking
+from repro.kernels.common import (fold_slots, gather_lanes, map_lanes,
+                                  rows_to_tiles, slot_fold_call, slot_tiles,
+                                  tiles_to_rows)
 
 
-def _kernel(idx_ref, val_ref, msk_ref, delta_ref, send_ref, rank_ref,
-            extra_ref, acc_ref, rank_out_ref, send_out_ref, *, damping: float,
-            tol: float, n_kblocks: int):
-    k = pl.program_id(1)
+def _kernel(g_ref, msk_ref, val_ref, rank_ref, extra_ref, acc_ref,
+            rank_out_ref, send_out_ref, *, damping: float, tol: float):
+    def slot(j):
+        # a slot's sum term in slot order: the same association with or
+        # without a lane axis, so a lane column is bit-identical to the
+        # single-frontier dispatch of that lane
+        return jnp.where(msk_ref[j] != 0, damping * val_ref[j] * g_ref[j],
+                         0.0)
 
-    idx = idx_ref[...]
-    val = val_ref[...]
-    msk = msk_ref[...]
-    delta = delta_ref[...]                  # (N,) or (N, L) lane frontier
-    send = send_ref[...]                    # matches delta's rank
+    acc = fold_slots(acc_ref, g_ref.shape[0], slot, jnp.add, 0.0)
 
-    if delta.ndim == 2:                     # K-lane SpMM: edge tile broadcast
-        val = val[..., None]                # over the trailing lane axis
-        msk = msk[..., None]
-    contrib = jnp.where(send[idx], delta[idx], 0.0)
-    contrib = jnp.where(msk, damping * val * contrib, 0.0)
-    partial = contrib[:, 0]
-    for j in range(1, contrib.shape[1]):    # sequential slice-axis fold, as
-        partial = partial + contrib[:, j]   # in ell_spmv: the order is the
-    # same with or without a lane axis, so a lane column is bit-identical
-    # to the single-frontier dispatch of that lane
-
-    accumulate_k(acc_ref, partial, jnp.add)
-
-    @pl.when(k == n_kblocks - 1)
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _epilogue():
         # fold in the sliced-ELL spill bins' pre-combined contributions so
         # the returned delta_in covers every edge slot of the row
-        d_in = acc_ref[...] + extra_ref[...]
+        d_in = acc + extra_ref[...]
         acc_ref[...] = d_in
         rank_out_ref[...] = rank_ref[...] + d_in
-        send_out_ref[...] = d_in > tol
+        send_out_ref[...] = (d_in > tol).astype(send_out_ref.dtype)
 
 
 def fused_pr_step_pallas(idx, val, msk, delta, send, rank, extra, *,
                          damping: float = 0.85, tol: float = 1e-4,
-                         block_rows: int = 256, block_slices: int = 128,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """-> (rank', delta_in, send').  With an (N, L) lane frontier ``delta``
     (per-seed personalized PageRank), ``send``/``rank``/``extra`` carry the
     same trailing L axis and all three outputs are (R, L)."""
-    r, kk = idx.shape
-    bm, bk, nkb, grid = ell_blocking(r, kk, block_rows, block_slices)
-    lanes = delta.shape[1:]                 # () SpMV or (L,) lane SpMM
+    tiles = slot_tiles(idx, val, msk)
 
-    front_spec = pl.BlockSpec(delta.shape, lambda i, k: (0,) * delta.ndim)
-    row_spec = pl.BlockSpec((bm,) + lanes,
-                            (lambda i, k: (i, 0)) if lanes
-                            else (lambda i, k: (i,)))
+    def call(delta, send, rank, extra):
+        lane_shape = delta.shape[1:]        # () SpMV or (L,) lane SpMM
+        acc, rank_out, send_out = slot_fold_call(
+            functools.partial(_kernel, damping=damping, tol=tol), tiles,
+            lane_shape[0] if lane_shape else 1,
+            # non-senders ride the gather as 0: damping * val * 0, as in
+            # the oracle, so no per-lane sender gate is needed
+            lane_edge=(gather_lanes(jnp.where(send, delta, 0.0), tiles),),
+            shared_edge=(tiles.msk, tiles.val),
+            row_ops=(rows_to_tiles(rank, tiles), rows_to_tiles(extra, tiles)),
+            out_dtypes=(rank.dtype, rank.dtype, jnp.int32),
+            interpret=interpret)
+        back = lambda t: tiles_to_rows(t, tiles, lane_shape)
+        return back(rank_out), back(acc), back(send_out) != 0
 
-    acc, rank_out, send_out = pl.pallas_call(
-        functools.partial(_kernel, damping=damping, tol=tol, n_kblocks=nkb),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-            front_spec,
-            front_spec,
-            row_spec,
-            row_spec,
-        ],
-        out_specs=[row_spec, row_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((r,) + lanes, rank.dtype),
-            jax.ShapeDtypeStruct((r,) + lanes, rank.dtype),
-            jax.ShapeDtypeStruct((r,) + lanes, jnp.bool_),
-        ],
-        interpret=interpret,
-    )(idx, val, msk, delta, send, rank, extra)
-    return rank_out, acc, send_out
+    return map_lanes(call, (delta, send, rank, extra), tiles)

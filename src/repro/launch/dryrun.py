@@ -24,7 +24,7 @@ import traceback
 import jax
 
 from repro.configs.base import SHAPES, get_config
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell, runnable
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", ".."))
@@ -34,10 +34,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 # graph workload (--graphhp / run_graphhp_cell).
 ARCHS: list[str] = []
 
+# the chip the production meshes are made of, as jax names its device_kind
+DEVICE_KIND = "TPU v5 lite"
+
 
 def _compile_once(cfg, shape, mesh, multi_pod, microbatches: int = 1):
     cell = build_cell(cfg, shape, mesh, multi_pod, microbatches=microbatches)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings)
         lowered = jitted.lower(*cell.args)
         compiled = lowered.compile()
@@ -163,10 +166,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                 cost.get("bytes accessed", 0) or 0) * mb
             coll_full *= mb
 
-        terms = roofline_terms(cost, hlo)
+        terms = roofline_terms(cost, hlo, DEVICE_KIND)
         terms["collective_bytes"] = coll_full
-        from benchmarks.roofline import ICI_BW
-        terms["t_collective_s"] = coll_full / ICI_BW
+        from benchmarks.roofline import peaks
+        terms["t_collective_s"] = (coll_full
+                                   / peaks(DEVICE_KIND)["ici_bytes_per_s"])
         terms["dominant"] = max(
             (("compute", terms["t_compute_s"]),
              ("memory", terms["t_memory_s"]),
@@ -232,7 +236,7 @@ def run_graphhp_cell(multi_pod: bool, out_dir: str, smoke: bool = False,
                           shard0_specs(graph, axes))
         ess = jax.tree.map(lambda s: NamedSharding(mesh, s),
                            _es_specs(es, axes))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(lambda g, e: step(g, e),
                              in_shardings=(gs, ess))
             lowered = jitted.lower(graph, es)
@@ -242,7 +246,7 @@ def run_graphhp_cell(multi_pod: bool, out_dir: str, smoke: bool = False,
         hlo = compiled.as_text()
         rec.update(status="ok", devices=int(mesh.size),
                    memory=_mem_dict(mem),
-                   roofline=roofline_terms(cost or {}, hlo),
+                   roofline=roofline_terms(cost or {}, hlo, DEVICE_KIND),
                    elapsed_s=round(time.time() - t0, 1))
         print(f"[ok] graphhp {mesh_tag}: {rec['roofline']['dominant']}",
               flush=True)
@@ -297,13 +301,13 @@ def run_sync_cell(arch: str, out_dir: str, compress: bool = True,
                               is_leaf=lambda x: isinstance(x, P))
         fn = functools.partial(global_sync, compress=compress,
                                gathered_specs=gspecs)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=(
                 named(pp_specs, mesh), named(outer_specs, mesh))
             ).lower(pp_shapes, outer_shapes).compile()
         cost = dict(compiled.cost_analysis() or {})
         hlo = compiled.as_text()
-        terms = roofline_terms(cost, hlo)
+        terms = roofline_terms(cost, hlo, DEVICE_KIND)
         rec.update(status="ok", devices=int(mesh.size),
                    memory=_mem_dict(compiled.memory_analysis()),
                    roofline=terms,

@@ -5,40 +5,29 @@ Multi-pod:  (pod=2, data=16, model=16) = 512 chips — the ``pod`` axis is the
 GraphHP partition axis for hybrid-sync training (DESIGN.md §6).
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run must set XLA_FLAGS before any jax initialization).
+state (the dry-run must set XLA_FLAGS before any jax initialization).  The
+axes are ``AxisType.Auto``: under ``jax.set_mesh`` eager code keeps working
+on arrays sharded over them, and the explicit shardings every call site
+passes (``shard_map``, ``NamedSharding``) decide placement.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context manager across jax versions.
-
-    Older releases have no public ambient-mesh context (the private
-    ``jax._src.mesh.set_mesh`` switches on sharding-in-types and breaks
-    plain ops there), so this degrades to a no-op — every call site also
-    passes the mesh explicitly (shard_map / NamedSharding), which is what
-    actually places the computation."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    import contextlib
-    return contextlib.nullcontext(mesh)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -46,4 +35,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -333,14 +333,16 @@ class ServeEngine:
         if ck not in self._full:
             prog = self._program(key, K)
 
-            def run(sources):
+            # the graph is an argument, not a closure: closed over, jit
+            # would bake every graph array into the program as a constant
+            def run(graph, sources):
                 # executes at trace time only: counts compiles per (key, K)
                 self.trace_counts[ck] = self.trace_counts.get(ck, 0) + 1
                 vdata = {"sources": sources}
-                es = self._policy.init(self.graph, prog, vdata)
+                es = self._policy.init(graph, prog, vdata)
                 return while_engine(
                     prog,
-                    lambda e: self._policy.step(self.graph, prog, e, vdata),
+                    lambda e: self._policy.step(graph, prog, e, vdata),
                     es, self.max_iters)
 
             self._full[ck] = jax.jit(run)
@@ -350,10 +352,12 @@ class ServeEngine:
         ck = (key, K)
         if ck not in self._step:
             prog = self._program(key, K)
-            self._init[ck] = jax.jit(lambda src: self._policy.init(
-                self.graph, prog, {"sources": src}))
-            self._step[ck] = jax.jit(lambda es, src: self._policy.step(
-                self.graph, prog, es, {"sources": src}))
+            init = jax.jit(lambda g, src: self._policy.init(
+                g, prog, {"sources": src}))
+            step = jax.jit(lambda g, es, src: self._policy.step(
+                g, prog, es, {"sources": src}))
+            self._init[ck] = lambda src: init(self.graph, src)
+            self._step[ck] = lambda es, src: step(self.graph, es, src)
 
             def changed(prev, state):
                 ch = jnp.zeros((K,), bool)
@@ -371,7 +375,7 @@ class ServeEngine:
     def _dispatch(self, key: tuple, K: int, sources, attempt: int):
         if self._dispatch_fn is not None:
             return self._dispatch_fn(self, key, K, sources, attempt)
-        return self._full_run(key, K)(sources)
+        return self._full_run(key, K)(self.graph, sources)
 
     def _dispatch_checkpointed(self, key: tuple, K: int, sources):
         """One batch through the checkpointing executor: host-stepped with

@@ -35,7 +35,7 @@ def test_distributed_hybrid_engine_matches_host():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.core import build_partitioned_graph, bfs_partition, run_hybrid
     from repro.core.apps import SSSP
@@ -55,7 +55,7 @@ def test_distributed_hybrid_engine_matches_host():
     ref = np.asarray(es_ref.state['dist'])
 
     # distributed: one partition per device
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_host_mesh(2, 4)   # Auto axes: eager code runs on sharded inputs
     axes = ('data', 'model')
     step = make_dist_hybrid_step(prog, mesh, axes=axes)
     es = init_hybrid(graph, prog, None)
@@ -63,12 +63,11 @@ def test_distributed_hybrid_engine_matches_host():
     ess = jax.tree.map(lambda s: NamedSharding(mesh, s), _es_specs(es, axes))
     graph_d = jax.device_put(graph, gs)
     es_d = jax.device_put(es, ess)
-    with set_mesh(mesh):
-        jitted = jax.jit(step, in_shardings=(gs, ess))
-        iters = 0
-        while not bool(quiescent(prog, es_d)) and iters < 500:
-            es_d = jitted(graph_d, es_d)
-            iters += 1
+    jitted = jax.jit(step, in_shardings=(gs, ess))
+    iters = 0
+    while not bool(quiescent(prog, es_d)) and iters < 500:
+        es_d = jitted(graph_d, es_d)
+        iters += 1
     got = np.asarray(jax.device_get(es_d.state['dist']))
     np.testing.assert_allclose(got, ref, rtol=1e-5)
     assert iters == iters_ref, (iters, iters_ref)
@@ -89,7 +88,7 @@ def test_distributed_hybrid_kernel_path_matches_host():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import NamedSharding
     from repro.core import build_partitioned_graph, hash_partition, run_hybrid
     from repro.core.apps import SSSP
@@ -115,7 +114,7 @@ def test_distributed_hybrid_kernel_path_matches_host():
     es_ref, iters_ref = run_hybrid(graph, prog, use_ell=False)
     ref = np.asarray(es_ref.state['dist'])
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_host_mesh(2, 4)   # Auto axes: eager code runs on sharded inputs
     axes = ('data', 'model')
     # kernel path + collect_metrics=True are the defaults now — no kwargs
     step = make_dist_hybrid_step(prog, mesh, axes=axes)
@@ -124,12 +123,11 @@ def test_distributed_hybrid_kernel_path_matches_host():
     ess = jax.tree.map(lambda s: NamedSharding(mesh, s), _es_specs(es, axes))
     graph_d = jax.device_put(graph, gs)
     es_d = jax.device_put(es, ess)
-    with set_mesh(mesh):
-        jitted = jax.jit(step, in_shardings=(gs, ess))
-        iters = 0
-        while not bool(quiescent(prog, es_d)) and iters < 500:
-            es_d = jitted(graph_d, es_d)
-            iters += 1
+    jitted = jax.jit(step, in_shardings=(gs, ess))
+    iters = 0
+    while not bool(quiescent(prog, es_d)) and iters < 500:
+        es_d = jitted(graph_d, es_d)
+        iters += 1
     got = np.asarray(jax.device_get(es_d.state['dist']))
     np.testing.assert_array_equal(got, ref)      # min semiring: bit-exact
     assert iters == iters_ref, (iters, iters_ref)
@@ -147,7 +145,7 @@ def test_distributed_new_semiring_apps_match_host():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import NamedSharding
     from repro.core import build_partitioned_graph, hash_partition, run_hybrid
     from repro.core.apps import RandomWalk, WidestPath
@@ -168,7 +166,7 @@ def test_distributed_new_semiring_apps_match_host():
         weights=random_walk_edge_weights(edges, n, m))
         for m in ('odds', 'logprob')}
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_host_mesh(2, 4)   # Auto axes: eager code runs on sharded inputs
     axes = ('data', 'model')
     cases = [('widest', g_cap, WidestPath(source=0), 'cap'),
              ('rw_odds', g_rw['odds'], RandomWalk(source=0, mode='odds'),
@@ -185,12 +183,11 @@ def test_distributed_new_semiring_apps_match_host():
                            _es_specs(es, axes))
         graph_d = jax.device_put(graph, gs)
         es_d = jax.device_put(es, ess)
-        with set_mesh(mesh):
-            jitted = jax.jit(step, in_shardings=(gs, ess))
-            iters = 0
-            while not bool(quiescent(prog, es_d)) and iters < 500:
-                es_d = jitted(graph_d, es_d)
-                iters += 1
+        jitted = jax.jit(step, in_shardings=(gs, ess))
+        iters = 0
+        while not bool(quiescent(prog, es_d)) and iters < 500:
+            es_d = jitted(graph_d, es_d)
+            iters += 1
         got = np.asarray(jax.device_get(es_d.state[field]))
         np.testing.assert_array_equal(got, np.asarray(es_ref.state[field]))
         assert iters == iters_ref, (name, iters, iters_ref)
@@ -210,7 +207,7 @@ def test_distributed_lane_frontiers_match_host():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import NamedSharding
     from repro.core import build_partitioned_graph, bfs_partition, run_hybrid
     from repro.core.apps import MultiSourceMonotone
@@ -227,7 +224,7 @@ def test_distributed_lane_frontiers_match_host():
     es_ref, iters_ref = run_hybrid(graph, prog)
     ref = np.asarray(es_ref.state['val'])
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_host_mesh(2, 4)   # Auto axes: eager code runs on sharded inputs
     axes = ('data', 'model')
     step = make_dist_hybrid_step(prog, mesh, axes=axes)
     es = init_hybrid(graph, prog, None)
@@ -236,12 +233,11 @@ def test_distributed_lane_frontiers_match_host():
     ess = jax.tree.map(lambda s: NamedSharding(mesh, s), _es_specs(es, axes))
     graph_d = jax.device_put(graph, gs)
     es_d = jax.device_put(es, ess)
-    with set_mesh(mesh):
-        jitted = jax.jit(step, in_shardings=(gs, ess))
-        iters = 0
-        while not bool(quiescent(prog, es_d)) and iters < 500:
-            es_d = jitted(graph_d, es_d)
-            iters += 1
+    jitted = jax.jit(step, in_shardings=(gs, ess))
+    iters = 0
+    while not bool(quiescent(prog, es_d)) and iters < 500:
+        es_d = jitted(graph_d, es_d)
+        iters += 1
     got = np.asarray(jax.device_get(es_d.state['val']))
     assert got.shape == ref.shape and got.ndim == 3
     np.testing.assert_array_equal(got, ref)
@@ -260,7 +256,7 @@ def _dist_ft_body(app: str) -> str:
     import tempfile
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import NamedSharding
     from repro.core import bfs_partition, build_partitioned_graph, \\
         hash_partition
@@ -283,7 +279,7 @@ def _dist_ft_body(app: str) -> str:
         prog, field = IncrementalPageRank(tolerance=1e-4), 'rank'
     graph = build_partitioned_graph(edges, n, part, weights=w,
                                     edge_blocks=8)   # one block per device
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_host_mesh(2, 4)   # Auto axes: eager code runs on sharded inputs
     axes = ('data', 'model')
     step = make_dist_hybrid_step(prog, mesh, axes=axes)
     es0 = init_hybrid(graph, prog, None)
@@ -292,7 +288,7 @@ def _dist_ft_body(app: str) -> str:
     ess = jax.tree.map(lambda s: NamedSharding(mesh, s),
                        _es_specs(es0, axes))
     graph_d = jax.device_put(graph, gs)
-    with set_mesh(mesh), tempfile.TemporaryDirectory() as d:
+    with tempfile.TemporaryDirectory() as d:
         ref = run_hybrid_ft(graph_d, prog, step_fn=step, es_shardings=ess)
         r1 = run_hybrid_ft(graph_d, prog, step_fn=step, es_shardings=ess,
                            ckpt_dir=d, max_iters=3)
@@ -327,7 +323,6 @@ def test_lm_cell_runs_on_mesh():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import ArchConfig, LayerSpec
     from repro.models.registry import get_model, param_shapes
@@ -354,7 +349,7 @@ def test_lm_cell_runs_on_mesh():
     from repro.optim.adamw import AdamWState
     ospecs = AdamWState(mu=pspecs, nu=pspecs, step=P())
     step_fn = make_train_step(cfg, api, peak_lr=1e-3)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, named(pspecs, mesh))
         opt = jax.device_put(opt, named(ospecs, mesh))
         batch = jax.device_put(batch, named(bspecs, mesh))
@@ -373,7 +368,6 @@ def test_decode_cell_seq_sharded_cache():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
     from repro.configs import ArchConfig, LayerSpec
     from repro.models.registry import get_model
     from repro.sharding.rules import cache_specs
@@ -398,7 +392,7 @@ def test_decode_cell_seq_sharded_cache():
     # sequence-sharded cache on the mesh
     cache = api.init_cache(cfg, 2, 32, jnp.float32)
     cspecs = sanitize_specs(cache_specs(cache), cache, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache = jax.device_put(cache, named(cspecs, mesh))
         logits, cache = jax.jit(lambda p, b, c: api.prefill(p, b, c, cfg))(
             params, {'tokens': tokens}, cache)
@@ -416,7 +410,6 @@ def test_hybrid_sync_on_pod_mesh():
     run_sub("""
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import set_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import ArchConfig, LayerSpec
     from repro.core.hybrid_sync import (global_sync, inner_steps, outer_init,
@@ -446,7 +439,7 @@ def test_hybrid_sync_on_pod_mesh():
     batch = {'tokens': jnp.asarray(rng.randint(0, cfg.vocab, (2, 4, 32), dtype=np.int32)),
              'labels': jnp.asarray(rng.randint(0, cfg.vocab, (2, 4, 32), dtype=np.int32))}
     outer = outer_init(params, n_pods)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pp = jax.device_put(pp, named(pspecs, mesh))
         inner = jax.jit(lambda p, o, b, s: inner_steps(step_fn, p, o, b, s))
         for s in range(2):

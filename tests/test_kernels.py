@@ -375,3 +375,53 @@ def test_fused_pr_step_lanes(lanes):
                                 rank[:, j], extra[:, j], tol=1e-3)
         for g, s in zip(got, singles):
             np.testing.assert_array_equal(np.asarray(g[:, j]), np.asarray(s))
+
+
+@pytest.mark.parametrize("semiring", SEMIRINGS)
+def test_ell_spmv_hub_bin_segments(semiring):
+    """A short, wide bin (a few hub rows, thousands of slots) is folded as
+    row segments: oracle parity, and lane columns still bit-identical to
+    single-lane dispatch."""
+    from repro.kernels.common import segment_count
+
+    r, k, n, lanes = 12, 2100, 500, 2
+    assert segment_count(r, k) > 1
+    rng = np.random.RandomState(17)
+    idx, val, msk, _ = _random_ell(rng, r, k, n)
+    x = jnp.asarray(rng.uniform(0.0, 3.0, size=(n, lanes)).astype(np.float32))
+    got = ell_spmv(idx, val, msk, x, semiring=semiring)
+    _assert_kernel_eq(got, ell_spmv_ref(idx, val, msk, x, semiring=semiring),
+                      semiring)
+    for j in range(lanes):
+        single = ell_spmv(idx, val, msk, x[:, j], semiring=semiring)
+        np.testing.assert_array_equal(np.asarray(got[:, j]),
+                                      np.asarray(single))
+
+
+def test_lane_groups_bit_identical(monkeypatch):
+    """Lane batches too wide for one gathered tile run in lane groups
+    (``lax.map``); every output of all three kernels is bit-identical to
+    the one-dispatch result."""
+    import repro.kernels.common as kc
+    from repro.kernels.ell_spmv.ell_spmv import ell_spmv_pallas
+    from repro.kernels.min_step.min_step import fused_min_step_pallas
+    from repro.kernels.pr_step.pr_step import fused_pr_step_pallas
+
+    r, k, n, lanes = 40, 16, 40, 4
+    rng = np.random.RandomState(19)
+    idx, val, msk, _ = _random_ell(rng, r, k, n)
+    x = jnp.asarray(rng.uniform(0.0, 3.0, size=(n, lanes)).astype(np.float32))
+    send = jnp.asarray(rng.uniform(size=(n, lanes)) < 0.5)
+    row = jnp.asarray(rng.uniform(0.0, 3.0, size=(r, lanes))
+                      .astype(np.float32))
+    calls = [
+        lambda: (ell_spmv_pallas(idx, val, msk, x, semiring="add_mul"),),
+        lambda: fused_min_step_pallas(idx, val, msk, x, send, row, row),
+        lambda: fused_pr_step_pallas(idx, val, msk, x, send, row, 0 * row,
+                                     tol=1e-3),
+    ]
+    whole = [call() for call in calls]
+    monkeypatch.setattr(kc, "LANE_TILE_ELEMS", 1)     # one lane per group
+    for call, want in zip(calls, whole):
+        for g, w in zip(call(), want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
