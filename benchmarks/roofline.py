@@ -2,7 +2,7 @@
 
 compute term    = HLO_FLOPs / peak_FLOPs          (per chip, cost_analysis)
 memory term     = HLO_bytes / HBM_bw              (per chip, cost_analysis)
-collective term = collective_bytes / ICI_bw       (per chip, parsed from HLO)
+collective term = collective_bytes / ICI_bw       (per link, parsed from HLO)
 
 with the peaks of the target ``device_kind`` from :data:`HARDWARE`.
 
@@ -15,13 +15,17 @@ from __future__ import annotations
 
 import re
 
-#: Published per-chip peaks, keyed by ``jax.Device.device_kind`` — the one
-#: source of hardware constants in the repo.  TPU v5e: Google Cloud
-#: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
-#: 1,600 Gbit/s of chip-to-chip interconnect).
+#: Published peaks, keyed by ``jax.Device.device_kind`` — the one source of
+#: hardware constants in the repo.  TPU v5e: Google Cloud documentation,
+#: "TPU v5e" (197 TFLOP/s bf16 and 16 GB HBM at 819 GB/s per chip; 1,600
+#: Gbit/s of chip-to-chip interconnect per chip).  The ring-cost factors of
+#: :func:`collective_bytes` count the bytes one link carries, so the ICI
+#: figure is per link: the per-chip 200 GB/s over the four links a chip has
+#: in the v5e's 2-D torus.
 HARDWARE = {
-    "TPU v5 lite": {"peak_flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
-                    "ici_bytes_per_s": 1600e9 / 8},
+    "TPU v5 lite": {"peak_flops_bf16_per_chip": 197e12,
+                    "hbm_bytes_per_s_per_chip": 819e9,
+                    "ici_bytes_per_s_per_link": 1600e9 / 8 / 4},
 }
 
 
@@ -124,9 +128,9 @@ def roofline_terms(cost: dict, hlo_text: str, device_kind: str) -> dict:
     flops = float(cost.get("flops", 0.0) or 0.0)
     bytes_hbm = float(cost.get("bytes accessed", 0.0) or 0.0)
     coll = collective_bytes(hlo_text)
-    t_compute = flops / hw["peak_flops_bf16"]
-    t_memory = bytes_hbm / hw["hbm_bytes_per_s"]
-    t_coll = coll["total"] / hw["ici_bytes_per_s"]
+    t_compute = flops / hw["peak_flops_bf16_per_chip"]
+    t_memory = bytes_hbm / hw["hbm_bytes_per_s_per_chip"]
+    t_coll = coll["total"] / hw["ici_bytes_per_s_per_link"]
     dominant = max((("compute", t_compute), ("memory", t_memory),
                     ("collective", t_coll)), key=lambda kv: kv[1])[0]
     return {

@@ -101,26 +101,26 @@ def run_engine(
     (``ctx.es``, ``ctx.iteration``).
 
     ``es`` seeds the loop (default: ``policy.init``); ``jit_step``
-    overrides the jitted step ``es -> es`` (callers with a compile cache —
-    the serving layer — or a shard_map step pass their own).
-    ``device_loop=True`` lowers the whole loop into one jit; hooks then
-    only see ``on_start`` / ``on_exit`` (there is no host boundary between
-    steps), so it rejects hooks that override the per-step methods.
+    overrides the jitted step ``es -> es`` of the host loop (callers with a
+    compile cache — the serving layer — or a shard_map step pass their
+    own).  ``device_loop=True`` lowers the whole loop into one jit of the
+    policy's step; hooks then only see ``on_start`` / ``on_exit`` (there is
+    no host boundary between steps), so it rejects hooks that override the
+    per-step methods, and it takes no ``jit_step``.
     """
+    if device_loop and jit_step is not None:
+        raise ValueError("device_loop=True jits the policy's own step; "
+                         "jit_step is for the host loop")
     if es is None:
         es = policy.init(graph, prog, vdata)
-    own_step = jit_step is None
-    if own_step:
-        # the graph and vdata are jit *arguments*: closed over, every graph
-        # array would be baked into the program as a constant
-        step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
-        jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
 
     ctx = ExecContext(graph=graph, prog=prog, policy=policy, vdata=vdata,
                       es=es, iteration=int(es.counters.iterations))
     for h in hooks:
         h.on_start(ctx)
 
+    # the graph and vdata are jit *arguments*: closed over, every graph
+    # array would be baked into the program as a constant
     if device_loop:
         stepwise = [h for h in hooks
                     if type(h).before_step is not ExecHook.before_step
@@ -130,15 +130,14 @@ def run_engine(
                 f"device_loop=True runs with no host boundary between "
                 f"steps; hooks {[type(h).__name__ for h in stepwise]} "
                 f"override before_step/after_step and need the host loop")
-        if own_step:
-            ctx.es = jax.jit(lambda g, v, e: while_engine(
-                prog, lambda e_: policy.step(g, prog, e_, v), e,
-                max_iters))(graph, vdata, ctx.es)
-        else:
-            ctx.es = jax.jit(
-                lambda e: while_engine(prog, jit_step, e, max_iters))(ctx.es)
+        ctx.es = jax.jit(lambda g, v, e: while_engine(
+            prog, lambda e_: policy.step(g, prog, e_, v), e,
+            max_iters))(graph, vdata, ctx.es)
         ctx.iteration = int(ctx.es.counters.iterations)
     else:
+        if jit_step is None:
+            step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
+            jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
         while (ctx.iteration < max_iters
                and not bool(quiescent(prog, ctx.es))):
             ctx.tick += 1

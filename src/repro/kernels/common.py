@@ -235,17 +235,14 @@ def fold_slots(acc_ref, n_slots: int, slot, combine, ident):
 
 
 def slot_fold_call(kernel, tiles: SlotTiles, lanes: int, *, lane_edge=(),
-                   shared_edge=(), row_ops=(), out_dtypes=(),
-                   interpret: bool | None = None):
+                   shared_edge=(), row_ops=(), out_dtypes=()):
     """One ``pallas_call`` on the slot-major grid (L·C/bc, Kp/bk).
 
     Operands, in the kernel's argument order: ``lane_edge`` tiles
     (Kp, 8, L·C), ``shared_edge`` tiles (Kp, 8, C) read by every lane,
     ``row_ops`` in the (8, L·C) row layout; outputs are (8, L·C) of
-    ``out_dtypes``, each block revisited along the K axis.  ``interpret``
-    defaults to :func:`default_interpret`."""
-    if interpret is None:
-        interpret = default_interpret()
+    ``out_dtypes``, each block revisited along the K axis.  Interpret mode
+    follows :func:`default_interpret`."""
     c, bk, bc = tiles.cols, tiles.bk, tiles.bc
     nrb = c // bc
     grid = (lanes * nrb, tiles.idx.shape[0] // bk)
@@ -261,7 +258,7 @@ def slot_fold_call(kernel, tiles: SlotTiles, lanes: int, *, lane_edge=(),
         out_specs=[row_spec] * len(out_dtypes),
         out_shape=[jax.ShapeDtypeStruct((8, lanes * c), dt)
                    for dt in out_dtypes],
-        interpret=interpret,
+        interpret=default_interpret(),
     )(*lane_edge, *shared_edge, *row_ops)
 
 

@@ -64,11 +64,9 @@ def ell_spmv_pallas(
     x: jax.Array,
     *,
     semiring: str = "add_mul",
-    interpret: bool | None = None,
 ) -> jax.Array:
     """y = ⊕_k val ⊗ x[idx] per row.  Returns (R,) for an (N,) frontier and
-    (R, L) for a stacked (N, L) lane frontier, in x.dtype.  ``interpret``
-    defaults to the platform's (``kernels.common.default_interpret``)."""
+    (R, L) for a stacked (N, L) lane frontier, in x.dtype."""
     r, k = idx.shape
     seg = segment_count(r, k)
     if seg > 1:                             # a hub bin: fold row segments
@@ -85,7 +83,7 @@ def ell_spmv_pallas(
             lane_shape[0] if lane_shape else 1,
             lane_edge=(gather_lanes(x, tiles),),
             shared_edge=(tiles.val, tiles.msk),
-            out_dtypes=(x.dtype,), interpret=interpret)
+            out_dtypes=(x.dtype,))
         return (tiles_to_rows(y, tiles, lane_shape),)
 
     (y,) = map_lanes(call, (x,), tiles)

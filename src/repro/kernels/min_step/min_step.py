@@ -70,8 +70,7 @@ def _kernel(g_ref, m_ref, val_ref, xrow_ref, extra_ref, acc_ref, x_out_ref,
 
 
 def fused_min_step_pallas(idx, val, msk, x, send, xrow, extra, *,
-                          semiring: str = "min_add",
-                          interpret: bool | None = None):
+                          semiring: str = "min_add"):
     """-> (x', d_in, send').  ``x`` is the (N,) frontier — or (N, L) for L
     independent query lanes, in which case ``send``/``xrow``/``extra`` carry
     the same trailing lane axis and all three outputs are (R, L).  ``xrow``
@@ -98,7 +97,7 @@ def fused_min_step_pallas(idx, val, msk, x, send, xrow, extra, *,
             functools.partial(_kernel, semiring=semiring), tiles,
             lane_shape[0] if lane_shape else 1, **edge,
             row_ops=(rows_to_tiles(xrow, tiles), rows_to_tiles(extra, tiles)),
-            out_dtypes=(x.dtype, x.dtype, jnp.int32), interpret=interpret)
+            out_dtypes=(x.dtype, x.dtype, jnp.int32))
         back = lambda t: tiles_to_rows(t, tiles, lane_shape)
         return back(x_out), back(acc), back(send_out) != 0
 

@@ -51,8 +51,7 @@ def _kernel(g_ref, msk_ref, val_ref, rank_ref, extra_ref, acc_ref,
 
 
 def fused_pr_step_pallas(idx, val, msk, delta, send, rank, extra, *,
-                         damping: float = 0.85, tol: float = 1e-4,
-                         interpret: bool | None = None):
+                         damping: float = 0.85, tol: float = 1e-4):
     """-> (rank', delta_in, send').  With an (N, L) lane frontier ``delta``
     (per-seed personalized PageRank), ``send``/``rank``/``extra`` carry the
     same trailing L axis and all three outputs are (R, L)."""
@@ -68,8 +67,7 @@ def fused_pr_step_pallas(idx, val, msk, delta, send, rank, extra, *,
             lane_edge=(gather_lanes(jnp.where(send, delta, 0.0), tiles),),
             shared_edge=(tiles.msk, tiles.val),
             row_ops=(rows_to_tiles(rank, tiles), rows_to_tiles(extra, tiles)),
-            out_dtypes=(rank.dtype, rank.dtype, jnp.int32),
-            interpret=interpret)
+            out_dtypes=(rank.dtype, rank.dtype, jnp.int32))
         back = lambda t: tiles_to_rows(t, tiles, lane_shape)
         return back(rank_out), back(acc), back(send_out) != 0
 

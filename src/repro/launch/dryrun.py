@@ -169,8 +169,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         terms = roofline_terms(cost, hlo, DEVICE_KIND)
         terms["collective_bytes"] = coll_full
         from benchmarks.roofline import peaks
-        terms["t_collective_s"] = (coll_full
-                                   / peaks(DEVICE_KIND)["ici_bytes_per_s"])
+        terms["t_collective_s"] = (
+            coll_full / peaks(DEVICE_KIND)["ici_bytes_per_s_per_link"])
         terms["dominant"] = max(
             (("compute", terms["t_compute_s"]),
              ("memory", terms["t_memory_s"]),

@@ -109,6 +109,21 @@ def test_later_hook_false_does_not_shortcircuit(road):
     assert ("a", "CONSUMED") in log and ("b", "CONSUMED") in log
 
 
+def test_device_loop_matches_host_loop_and_takes_no_step(road):
+    """The one-jit device loop reaches the host loop's fixed point in the
+    same iterations; it jits the policy's own step, so a caller's
+    ``jit_step`` is refused rather than closed over."""
+    policy = make_policy("hybrid")
+    ref = run_engine(road, SSSP(source=0), policy, None)
+    ctx = run_engine(road, SSSP(source=0), policy, None, device_loop=True)
+    assert ctx.iteration == ref.iteration
+    np.testing.assert_array_equal(np.asarray(ctx.es.state["dist"]),
+                                  np.asarray(ref.es.state["dist"]))
+    with pytest.raises(ValueError, match="jit_step"):
+        run_engine(road, SSSP(source=0), policy, None, device_loop=True,
+                   jit_step=lambda e: e)
+
+
 def test_checkpoint_fault_and_trace_hooks_compose(road, tmp_path):
     """The production stack — fault detection + checkpointing + tracing on
     one run — leaves results identical to the bare run and a consistent

@@ -5,8 +5,11 @@ The TPU compiler is installed alongside jax and compiles for a described
 mode cannot — layouts Mosaic refuses, in-kernel gathers, VMEM overruns,
 programs larger than the chip's HBM — with no chip.  Each compiles one
 kernel at the widths of a scale-20 graph (2**20 rows and frontier slots),
-for one lane and for a 16-lane query batch, and asserts that the kernel
-really lowered to Mosaic (``tpu_custom_call``) rather than interpret mode.
+for one lane and for a 16-lane query batch — the fused monotone step for
+every semiring it takes, since min_mul alone carries a per-lane sender gate
+— and asserts that the kernel really lowered to Mosaic (``tpu_custom_call``)
+rather than interpret mode: tracing on the CPU backend would pick interpret
+mode, so the ``mosaic`` fixture makes ``default_interpret`` answer False.
 
 The topology is described inside a fixture: only the worker that runs
 these tests loads the TPU library, and every worker collects them.
@@ -19,6 +22,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.common import MONOTONE_SEMIRINGS
 
 ROWS = 1 << 20          # P * Vp destination rows of a scale-20 graph
 SLOTS = 16              # base-bin slot width
@@ -40,6 +45,12 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    import repro.kernels.common as common
+    monkeypatch.setattr(common, "default_interpret", lambda: False)
 
 
 def _shape(one_chip, shape, dtype):
@@ -69,20 +80,20 @@ def _assert_mosaic(compiled):
 
 
 @pytest.mark.parametrize("lanes", LANES)
-def test_ell_spmv_compiles_for_v5e(one_chip, lanes):
+def test_ell_spmv_compiles_for_v5e(one_chip, mosaic, lanes):
     from repro.kernels.ell_spmv.ell_spmv import ell_spmv_pallas
 
     lane = (lanes,) if lanes else ()
     x = _shape(one_chip, (FRONTIER,) + lane, jnp.float32)
     compiled = _compile(
-        lambda i, v, m, x: ell_spmv_pallas(i, v, m, x, semiring="min_add",
-                                           interpret=False),
+        lambda i, v, m, x: ell_spmv_pallas(i, v, m, x, semiring="min_add"),
         *_edges(one_chip), x)
     _assert_mosaic(compiled)
 
 
+@pytest.mark.parametrize("semiring", sorted(MONOTONE_SEMIRINGS))
 @pytest.mark.parametrize("lanes", LANES)
-def test_fused_min_step_compiles_for_v5e(one_chip, lanes):
+def test_fused_min_step_compiles_for_v5e(one_chip, mosaic, lanes, semiring):
     from repro.kernels.min_step.min_step import fused_min_step_pallas
 
     lane = (lanes,) if lanes else ()
@@ -91,13 +102,13 @@ def test_fused_min_step_compiles_for_v5e(one_chip, lanes):
     row = _shape(one_chip, (ROWS,) + lane, jnp.float32)
     compiled = _compile(
         lambda i, v, m, x, s, xr, e: fused_min_step_pallas(
-            i, v, m, x, s, xr, e, semiring="min_add", interpret=False),
+            i, v, m, x, s, xr, e, semiring=semiring),
         *_edges(one_chip), x, send, row, row)
     _assert_mosaic(compiled)
 
 
 @pytest.mark.parametrize("lanes", LANES)
-def test_fused_pr_step_compiles_for_v5e(one_chip, lanes):
+def test_fused_pr_step_compiles_for_v5e(one_chip, mosaic, lanes):
     from repro.kernels.pr_step.pr_step import fused_pr_step_pallas
 
     lane = (lanes,) if lanes else ()
@@ -106,6 +117,6 @@ def test_fused_pr_step_compiles_for_v5e(one_chip, lanes):
     row = _shape(one_chip, (ROWS,) + lane, jnp.float32)
     compiled = _compile(
         lambda i, v, m, d, s, r, e: fused_pr_step_pallas(
-            i, v, m, d, s, r, e, interpret=False),
+            i, v, m, d, s, r, e),
         *_edges(one_chip), delta, send, row, row)
     _assert_mosaic(compiled)
