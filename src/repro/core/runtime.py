@@ -291,10 +291,12 @@ def ell_send_accounting(graph: PartitionedGraph, slices, views, send_flat,
             has = jnp.logical_or(has, row_has)
         else:
             has = has.at[rows].max(row_has, mode="drop")
-        mem += jnp.sum(tile).astype(jnp.int32)
+        with jax.named_scope("message_accounting"):
+            mem += jnp.sum(tile).astype(jnp.int32)
     return has.reshape(p, graph.vp), mem
 
 
+@jax.named_scope("message_accounting")
 def ell_group_accounting(graph: PartitionedGraph, slices, views, send_flat,
                          p: int) -> jax.Array:
     """Combined-message count at the paper's Combine() granularity — one per
@@ -370,8 +372,9 @@ def _ell_deliver(graph, prog, chs, es, pending, delivered, collect_metrics,
         if collect_metrics and edges == "local":
             # local deliveries: one combine group per messaged destination
             # (same-partition source), every valid edge an in-memory message
-            net_local += jnp.sum(has_fresh).astype(jnp.int32)
-            mem += mem_edges
+            with jax.named_scope("message_accounting"):
+                net_local += jnp.sum(has_fresh).astype(jnp.int32)
+                mem += mem_edges
 
     if collect_metrics and edges == "remote" and chs:
         # remote deliveries count per (source-partition, destination) combine
@@ -494,15 +497,18 @@ def deliver(
             if not collect_metrics:
                 continue
             # --- paper metrics ---------------------------------------------
-            grp_sent = jax.ops.segment_max(
-                valid_flat.astype(jnp.int32), gseg,
-                num_segments=bsz * graph.gp).reshape(bsz, graph.gp) > 0
-            grp_sent = jnp.logical_and(grp_sent, graph.group_mask)
-            net += jnp.sum(jnp.logical_and(grp_sent, graph.group_remote)).astype(jnp.int32)
-            net_local += jnp.sum(
-                jnp.logical_and(grp_sent, jnp.logical_not(graph.group_remote))
-            ).astype(jnp.int32)
-            mem += jnp.sum(jnp.logical_and(valid, graph.edge_local)).astype(jnp.int32)
+            with jax.named_scope("message_accounting"):
+                grp_sent = jax.ops.segment_max(
+                    valid_flat.astype(jnp.int32), gseg,
+                    num_segments=bsz * graph.gp).reshape(bsz, graph.gp) > 0
+                grp_sent = jnp.logical_and(grp_sent, graph.group_mask)
+                net += jnp.sum(jnp.logical_and(
+                    grp_sent, graph.group_remote)).astype(jnp.int32)
+                net_local += jnp.sum(jnp.logical_and(
+                    grp_sent, jnp.logical_not(graph.group_remote))
+                ).astype(jnp.int32)
+                mem += jnp.sum(jnp.logical_and(
+                    valid, graph.edge_local)).astype(jnp.int32)
 
     c = es.counters
     counters = dataclasses.replace(

@@ -19,6 +19,13 @@ Two lowerings of the same loop:
 
 The driver is the only place an outer iteration loop exists; the policy
 modules contain step bodies, the engine modules contain configuration.
+
+Every :func:`run_engine` call opens host spans (:func:`repro.obs.span`,
+recorded only inside a ``jax.profiler`` session): ``engine.run`` around
+the call, ``engine.init`` around ``policy.init``, and on the device-loop
+path ``engine.dispatch`` (trace, lower, compile or cache load, enqueue),
+``engine.loop_trace`` (inside the traced function: recorded once per
+trace of the loop) and ``engine.wait`` (the host blocked on the device).
 """
 
 from __future__ import annotations
@@ -31,6 +38,7 @@ import jax.numpy as jnp
 
 from repro.core.runtime import EngineState, quiescent
 from repro.exec.policy import EnginePolicy
+from repro.obs import span
 
 __all__ = ["run_engine", "while_engine", "ExecContext", "ExecHook"]
 
@@ -111,45 +119,64 @@ def run_engine(
     if device_loop and jit_step is not None:
         raise ValueError("device_loop=True jits the policy's own step; "
                          "jit_step is for the host loop")
-    if es is None:
-        es = policy.init(graph, prog, vdata)
+    with span("engine.run", engine=policy.name, device_loop=device_loop):
+        if es is None:
+            with span("engine.init"):
+                es = policy.init(graph, prog, vdata)
+        ctx = ExecContext(graph=graph, prog=prog, policy=policy, vdata=vdata,
+                          es=es, iteration=int(es.counters.iterations))
+        for h in hooks:
+            h.on_start(ctx)
+        if device_loop:
+            _device_loop(ctx, hooks, max_iters)
+        else:
+            _host_loop(ctx, hooks, max_iters, jit_step)
+        for h in hooks:
+            h.on_exit(ctx)
+    return ctx
 
-    ctx = ExecContext(graph=graph, prog=prog, policy=policy, vdata=vdata,
-                      es=es, iteration=int(es.counters.iterations))
-    for h in hooks:
-        h.on_start(ctx)
+
+def _device_loop(ctx: ExecContext, hooks: Sequence[ExecHook],
+                 max_iters: int) -> None:
+    """The whole loop as one jit of the policy's step; one host sync."""
+    stepwise = [h for h in hooks
+                if type(h).before_step is not ExecHook.before_step
+                or type(h).after_step is not ExecHook.after_step]
+    if stepwise:
+        raise ValueError(
+            f"device_loop=True runs with no host boundary between "
+            f"steps; hooks {[type(h).__name__ for h in stepwise]} "
+            f"override before_step/after_step and need the host loop")
+    prog, policy = ctx.prog, ctx.policy
 
     # the graph and vdata are jit *arguments*: closed over, every graph
     # array would be baked into the program as a constant
-    if device_loop:
-        stepwise = [h for h in hooks
-                    if type(h).before_step is not ExecHook.before_step
-                    or type(h).after_step is not ExecHook.after_step]
-        if stepwise:
-            raise ValueError(
-                f"device_loop=True runs with no host boundary between "
-                f"steps; hooks {[type(h).__name__ for h in stepwise]} "
-                f"override before_step/after_step and need the host loop")
-        ctx.es = jax.jit(lambda g, v, e: while_engine(
-            prog, lambda e_: policy.step(g, prog, e_, v), e,
-            max_iters))(graph, vdata, ctx.es)
-        ctx.iteration = int(ctx.es.counters.iterations)
-    else:
-        if jit_step is None:
-            step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
-            jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
-        while (ctx.iteration < max_iters
-               and not bool(quiescent(prog, ctx.es))):
-            ctx.tick += 1
-            # evaluate every hook (clocks must advance even when another
-            # hook consumes the tick), then skip the step if any said so
-            if False in [h.before_step(ctx) for h in hooks]:
-                continue            # a hook consumed this tick (restore)
-            ctx.es = jit_step(ctx.es)
-            ctx.iteration = int(ctx.es.counters.iterations)
-            for h in hooks:
-                h.after_step(ctx)
+    def loop(g, v, e):
+        with span("engine.loop_trace"):
+            return while_engine(
+                prog, lambda e_: policy.step(g, prog, e_, v), e, max_iters)
 
-    for h in hooks:
-        h.on_exit(ctx)
-    return ctx
+    with span("engine.dispatch"):
+        ctx.es = jax.jit(loop)(ctx.graph, ctx.vdata, ctx.es)
+    with span("engine.wait"):
+        ctx.iteration = int(ctx.es.counters.iterations)
+
+
+def _host_loop(ctx: ExecContext, hooks: Sequence[ExecHook], max_iters: int,
+               jit_step: Callable | None) -> None:
+    """One jitted step per host trip, with the hooks between steps."""
+    if jit_step is None:
+        prog, policy, graph, vdata = ctx.prog, ctx.policy, ctx.graph, ctx.vdata
+        step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
+        jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
+    while (ctx.iteration < max_iters
+           and not bool(quiescent(ctx.prog, ctx.es))):
+        ctx.tick += 1
+        # evaluate every hook (clocks must advance even when another
+        # hook consumes the tick), then skip the step if any said so
+        if False in [h.before_step(ctx) for h in hooks]:
+            continue            # a hook consumed this tick (restore)
+        ctx.es = jit_step(ctx.es)
+        ctx.iteration = int(ctx.es.counters.iterations)
+        for h in hooks:
+            h.after_step(ctx)

@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.graph import PartitionedGraph
@@ -58,9 +59,12 @@ def _deliver_split(graph, prog, es, use_ell, collect_metrics):
 # The observability layer (:mod:`repro.obs`) jits and times them one by one
 # to attribute wall time to exchange / delivery / compute / local phases —
 # they must compose to *exactly* the unsplit bodies (the golden parity
-# suite pins the composed results bit-identical).
+# suite pins the composed results bit-identical).  Each runs under a
+# ``jax.named_scope`` of its phase, so a device trace names the phase of
+# every op; the scope changes HLO metadata only, never what is computed.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("exchange")
 def exchange_phase(graph, prog, es, gather_table=None,
                    wire_dtype=None) -> EngineState:
     """The one distributed communication of a superstep / global iteration:
@@ -69,6 +73,7 @@ def exchange_phase(graph, prog, es, gather_table=None,
     return reset_export(prog, es)
 
 
+@jax.named_scope("bsp_delivery")
 def bsp_delivery(graph, prog, es, use_ell: bool = True,
                  collect_metrics: bool = True) -> EngineState:
     """Hama's delivery: every edge (remote + local halves on the ELL path,
@@ -76,6 +81,7 @@ def bsp_delivery(graph, prog, es, use_ell: bool = True,
     return _deliver_split(graph, prog, es, use_ell, collect_metrics)
 
 
+@jax.named_scope("bsp_compute")
 def bsp_compute(graph, prog, es, vdata) -> EngineState:
     """Hama's bulk Compute() over all (active ∨ messaged) vertices, plus
     the superstep counter bump."""
@@ -89,6 +95,7 @@ def bsp_compute(graph, prog, es, vdata) -> EngineState:
             pseudo_supersteps=c.pseudo_supersteps + 1))
 
 
+@jax.named_scope("remote_delivery")
 def hybrid_remote_delivery(graph, prog, es, use_ell: bool = True,
                            collect_metrics: bool = True) -> EngineState:
     """GraphHP: deliver the just-exchanged remote messages into pending."""
@@ -97,6 +104,7 @@ def hybrid_remote_delivery(graph, prog, es, use_ell: bool = True,
     return es
 
 
+@jax.named_scope("global_phase")
 def hybrid_global_phase(graph, prog, es, vdata, use_ell: bool = True,
                         collect_metrics: bool = True) -> EngineState:
     """GraphHP's global phase: boundary vertices Compute() exactly once,
@@ -114,6 +122,7 @@ def hybrid_global_phase(graph, prog, es, vdata, use_ell: bool = True,
     return es
 
 
+@jax.named_scope("local_phase")
 def hybrid_local(graph, prog, es, vdata, max_local_steps: int = 100_000,
                  use_ell: bool = True,
                  collect_metrics: bool = True) -> EngineState:

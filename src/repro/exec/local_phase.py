@@ -216,8 +216,9 @@ def _fused_pr_local_phase(
             # K-lane message counts once — vertex-level send)
             has_n, mem_inc = ell_send_accounting(graph, slices, views,
                                                  vany(send).reshape(-1), p)
-            net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
-            mem = mem + mem_inc
+            with jax.named_scope("message_accounting"):
+                net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
+                mem = mem + mem_inc
         else:
             has_n = vany(d_in > 0)     # positive-contribution invariant
         if lanes:
@@ -338,8 +339,9 @@ def _fused_min_local_phase(
         if collect_metrics:
             has_n, mem_inc = ell_send_accounting(graph, slices, views,
                                                  vany(send).reshape(-1), p)
-            net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
-            mem = mem + mem_inc
+            with jax.named_scope("message_accounting"):
+                net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
+                mem = mem + mem_inc
         else:
             # some sender beat the identity (any lane)
             has_n = vany(improves(d_n, sr_ident))

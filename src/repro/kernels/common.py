@@ -234,9 +234,10 @@ def fold_slots(acc_ref, n_slots: int, slot, combine, ident):
     return acc
 
 
-def slot_fold_call(kernel, tiles: SlotTiles, lanes: int, *, lane_edge=(),
-                   shared_edge=(), row_ops=(), out_dtypes=()):
-    """One ``pallas_call`` on the slot-major grid (L·C/bc, Kp/bk).
+def slot_fold_call(kernel, tiles: SlotTiles, lanes: int, *, name: str,
+                   lane_edge=(), shared_edge=(), row_ops=(), out_dtypes=()):
+    """One ``pallas_call`` named ``name`` on the slot-major grid
+    (L·C/bc, Kp/bk): the name is the kernel's in a device trace.
 
     Operands, in the kernel's argument order: ``lane_edge`` tiles
     (Kp, 8, L·C), ``shared_edge`` tiles (Kp, 8, C) read by every lane,
@@ -259,6 +260,7 @@ def slot_fold_call(kernel, tiles: SlotTiles, lanes: int, *, lane_edge=(),
         out_shape=[jax.ShapeDtypeStruct((8, lanes * c), dt)
                    for dt in out_dtypes],
         interpret=default_interpret(),
+        name=name,
     )(*lane_edge, *shared_edge, *row_ops)
 
 

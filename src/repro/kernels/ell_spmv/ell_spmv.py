@@ -81,6 +81,7 @@ def ell_spmv_pallas(
         (y,) = slot_fold_call(
             functools.partial(_kernel, semiring=semiring), tiles,
             lane_shape[0] if lane_shape else 1,
+            name="ell_spmv",
             lane_edge=(gather_lanes(x, tiles),),
             shared_edge=(tiles.val, tiles.msk),
             out_dtypes=(x.dtype,))

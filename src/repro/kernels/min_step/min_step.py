@@ -95,7 +95,8 @@ def fused_min_step_pallas(idx, val, msk, x, send, xrow, extra, *,
                         shared_edge=(tiles.val,))
         acc, x_out, send_out = slot_fold_call(
             functools.partial(_kernel, semiring=semiring), tiles,
-            lane_shape[0] if lane_shape else 1, **edge,
+            lane_shape[0] if lane_shape else 1,
+            name="min_step", **edge,
             row_ops=(rows_to_tiles(xrow, tiles), rows_to_tiles(extra, tiles)),
             out_dtypes=(x.dtype, x.dtype, jnp.int32))
         back = lambda t: tiles_to_rows(t, tiles, lane_shape)

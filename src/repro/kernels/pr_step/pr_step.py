@@ -62,6 +62,7 @@ def fused_pr_step_pallas(idx, val, msk, delta, send, rank, extra, *,
         acc, rank_out, send_out = slot_fold_call(
             functools.partial(_kernel, damping=damping, tol=tol), tiles,
             lane_shape[0] if lane_shape else 1,
+            name="pr_step",
             # non-senders ride the gather as 0: damping * val * 0, as in
             # the oracle, so no per-lane sender gate is needed
             lane_edge=(gather_lanes(jnp.where(send, delta, 0.0), tiles),),

@@ -1,8 +1,13 @@
 """Observability: superstep tracing, phase metrics, exporters, the
-BSP-vs-hybrid report CLI, and the one injectable clock.
+BSP-vs-hybrid report CLI, the one injectable clock, and :func:`span`.
+
+:func:`span` is the one span primitive on the device trace's clock: a
+``jax.profiler.TraceAnnotation``, recorded only while a profiler session
+(``jax.profiler.trace``) is open and otherwise a no-op of about a
+microsecond, so the executor opens its ``engine.*`` spans unconditionally.
 
 Layout (each submodule is importable on its own; nothing on the engines'
-hot path imports this package — hooks and wrappers are opt-in):
+hot path imports them — hooks and wrappers are opt-in):
 
 * :mod:`repro.obs.clock`   — the injectable monotonic / perf clock every
   time-consuming subsystem (ft, checkpoint, serve) routes through.
@@ -15,8 +20,9 @@ hot path imports this package — hooks and wrappers are opt-in):
 * :mod:`repro.obs.report`  — ``python -m repro.obs.report``: the paper's
   headline exchange-vs-compute comparison, measured.
 
-``from repro.obs import clock`` is the only import light enough for
-leaf modules (it pulls nothing but stdlib ``time``); everything else is
+``from repro.obs import clock`` and ``span`` are the only imports light
+enough for leaf modules (the clock pulls nothing but stdlib ``time``;
+``span`` imports ``jax.profiler`` when first called); everything else is
 loaded lazily through ``__getattr__`` so wiring ``obs`` into a module
 costs nothing until a tracer or registry is actually constructed.
 """
@@ -29,7 +35,16 @@ from repro.obs import clock  # noqa: F401  (stdlib-only; safe everywhere)
 
 _SUBMODULES = ("trace", "metrics", "export", "report")
 
-__all__ = ["clock", *_SUBMODULES]
+__all__ = ["clock", "span", *_SUBMODULES]
+
+
+def span(name: str, **args):
+    """A host span ``name`` (with ``args`` as its stats) on the profiler's
+    clock, as a context manager: it lands in the same trace as the device
+    ops, and costs next to nothing outside a profiler session."""
+    from jax import profiler
+
+    return profiler.TraceAnnotation(name, **args)
 
 
 def __getattr__(name: str):

@@ -2,15 +2,18 @@
 
 Three granularities, one :class:`Tracer`:
 
-* **run-level** — :class:`RunTraceHook` brackets a whole ``run_engine``
-  call in one span.  This is the degraded mode ``device_loop=True`` runs
-  get: the driver rejects stepwise hooks there (no host boundary between
-  steps), so only start/exit instrumentation is possible.
+* **run-level** — :func:`repro.exec.driver.run_engine` opens its own
+  ``engine.*`` spans (:func:`repro.obs.span`) on every call: the whole
+  run, the init, the dispatch of the jitted loop, the loop's trace and
+  the wait for the device.  They land in a ``jax.profiler`` trace beside
+  the device ops, whose phases carry ``jax.named_scope`` names
+  (:mod:`repro.exec.iteration`).  This is all a ``device_loop=True`` run
+  records: there is no host boundary between its steps.
 * **superstep-level** — :class:`TraceHook` records one span per executor
   step with the counter deltas and the exchange bytes the step is about
   to put on the wire.  Works on every host-driven run path (``run_bsp``
   / ``run_am`` / ``run_hybrid(device_loop=False)`` / ``run_hybrid_ft`` /
-  ``ServeEngine``); :func:`trace_hooks` picks the right hook class.
+  ``ServeEngine``); :func:`trace_hooks` picks it.
 * **phase-level** — :func:`phased_run` executes an engine's superstep as
   its composable phase functions (:mod:`repro.exec.iteration`), jitting
   and timing each phase separately: exchange, delivery, global apply,
@@ -18,10 +21,12 @@ Three granularities, one :class:`Tracer`:
   phase functions *are* the step body), so phase attribution costs only
   the extra dispatch boundaries.
 
-Disabled is free: nothing on the engine hot path imports this module, a
-``None``/disabled tracer contributes zero hooks (:func:`trace_hooks`
-returns ``()``), and all accounting (exchange bytes, counter deltas) runs
-only when a span is actually being recorded.
+A :class:`Tracer` keeps its spans in memory for the Chrome export;
+:meth:`Tracer.span` also opens a :func:`repro.obs.span`, so its blocks
+show in a profiler trace too.  A ``None``/disabled tracer contributes
+zero hooks (:func:`trace_hooks` returns ``()``), and all accounting
+(exchange bytes, counter deltas) runs only when a span is actually being
+recorded.
 
 :func:`wrap_hooks` decorates any other executor hook (checkpointing, the
 FT fault hook) so its per-method work shows up as ``cat="hook"`` spans —
@@ -36,9 +41,9 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
-from repro.obs import clock
+from repro.obs import clock, span as obs_span
 
-__all__ = ["Span", "Tracer", "TraceHook", "RunTraceHook", "trace_hooks",
+__all__ = ["Span", "Tracer", "TraceHook", "trace_hooks",
            "wrap_hooks", "exchange_bytes", "exchange_bytes_per_partition",
            "halo_slots_per_partition", "phased_run", "SuperstepRecord",
            "PhasedRunResult", "COMM_PHASES"]
@@ -84,15 +89,18 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "", tid: int = 0, **args):
-        """Record the block as one complete span; the yielded dict can be
-        mutated to attach args discovered inside the block."""
+        """Record the block as one complete span, in memory and in a
+        profiler trace if one is open; the yielded dict can be mutated to
+        attach args discovered inside the block (the profiler's copy
+        keeps the args given here)."""
         mutable = dict(args)
         if not self.enabled:
             yield mutable
             return
         t0 = clock.perf_counter()
         try:
-            yield mutable
+            with obs_span(name, **args):
+                yield mutable
         finally:
             self.spans.append(Span(name, t0, clock.perf_counter() - t0,
                                    cat, tid, "X", mutable))
@@ -181,10 +189,10 @@ class TraceHook(ExecHook):
     counter deltas as args.
 
     Stepwise — rejected by ``device_loop=True`` runs (no host boundary
-    between steps); use :func:`trace_hooks` to degrade to a
-    :class:`RunTraceHook` there.  Put this hook *last* in the hook list:
-    span order then brackets the step plus the preceding hooks' after-work
-    (wrap those with :func:`wrap_hooks` to see their cost separately).
+    between steps; :func:`trace_hooks` hands those none).  Put this hook
+    *last* in the hook list: span order then brackets the step plus the
+    preceding hooks' after-work (wrap those with :func:`wrap_hooks` to see
+    their cost separately).
     """
 
     def __init__(self, tracer: Tracer, tid: int = 0, wire_dtype=None):
@@ -220,45 +228,15 @@ class TraceHook(ExecHook):
         self._before = None
 
 
-class RunTraceHook(ExecHook):
-    """Run-level span only (``on_start``/``on_exit``) — the most a
-    ``device_loop=True`` run can report, since the whole loop is one jit
-    with no host boundary between steps."""
-
-    def __init__(self, tracer: Tracer, tid: int = 0):
-        self.tracer = tracer
-        self.tid = tid
-        self._t0 = 0.0
-        self._before: dict | None = None
-
-    def on_start(self, ctx: ExecContext) -> None:
-        if not self.tracer.enabled:
-            return
-        self._before = _counters_host(ctx.es.counters)
-        self._t0 = clock.perf_counter()
-
-    def on_exit(self, ctx: ExecContext) -> None:
-        if not self.tracer.enabled or self._before is None:
-            return
-        import jax
-        jax.block_until_ready(ctx.es)
-        after = _counters_host(ctx.es.counters)
-        self.tracer.add(
-            "run", self._t0, clock.perf_counter() - self._t0, cat="engine",
-            tid=self.tid, iterations=ctx.iteration,
-            **_counter_deltas(self._before, after))
-
-
 def trace_hooks(tracer: Tracer | None, device_loop: bool = False,
                 tid: int = 0, wire_dtype=None) -> tuple[ExecHook, ...]:
     """The hooks a run should carry for ``tracer``: ``()`` when tracing is
-    off (the disabled path adds zero hooks, zero work), a stepwise
-    :class:`TraceHook` on host-driven runs, a :class:`RunTraceHook` under
-    ``device_loop=True`` (stepwise hooks are rejected there)."""
-    if tracer is None or not tracer.enabled:
+    off (the disabled path adds zero hooks, zero work) or under
+    ``device_loop=True`` (no host boundary between steps: the run's own
+    ``engine.*`` spans are all it records), a stepwise :class:`TraceHook`
+    on host-driven runs."""
+    if tracer is None or not tracer.enabled or device_loop:
         return ()
-    if device_loop:
-        return (RunTraceHook(tracer, tid=tid),)
     return (TraceHook(tracer, tid=tid, wire_dtype=wire_dtype),)
 
 

@@ -152,6 +152,22 @@ def test_golden_parity(workloads, golden, app, engine, delivery, use_ell):
         f"snapshot"
 
 
+@pytest.mark.parametrize("app", ["sssp", "pagerank", "widest"])
+def test_golden_parity_inside_a_profiler_session(workloads, golden, app,
+                                                 tmp_path):
+    """The phase scopes, kernel names and engine spans change metadata
+    only: a hybrid run recorded by an open profiler session still lands
+    on the golden state, iterations and counters."""
+    import jax
+    graph, make_prog, vdata = workloads[app]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path / "profile"),
+                            profiler_options=opts):
+        got = _snapshot(graph, make_prog(), vdata, "hybrid", True)
+    assert got == golden[app]["hybrid"]["ell"]
+
+
 def regen() -> None:
     golden = {}
     for app, (graph, make_prog, vdata) in _workloads().items():

@@ -1,8 +1,10 @@
 """Observability subsystem: the injectable clock, the metrics registry,
-span tracing through the executor, the phased profiler's bit-parity with
-the fused engines, Chrome trace-event export, and the zero-cost guarantee
-for the disabled path."""
+span tracing through the executor, the executor's own spans and the phase
+scopes in a profiler trace, the phased profiler's bit-parity with the
+fused engines, Chrome trace-event export, and the zero-cost guarantee for
+the disabled path."""
 
+import glob
 import json
 import os
 import subprocess
@@ -18,15 +20,16 @@ from repro.core import (bfs_partition, build_partitioned_graph,
 from repro.core.apps import SSSP, IncrementalPageRank
 from repro.core.apps.pagerank import pagerank_edge_weights
 from repro.data.graphs import grid_graph, rmat_graph
-from repro.exec.policy import make_policy
+from repro.exec.policy import EnginePolicy, make_policy
 from repro.exec.driver import run_engine
 from repro.ft import FaultInjector, FaultPlan, run_hybrid_ft
 from repro.obs import clock as obs_clock
+from repro.obs import span
 from repro.obs.export import chrome_trace, profile_blob, write_chrome_trace
 from repro.obs.metrics import (MetricsRegistry, load_registry,
                                record_engine_counters, save_registry)
-from repro.obs.trace import (RunTraceHook, TraceHook, Tracer, exchange_bytes,
-                             phased_run, trace_hooks, wrap_hooks)
+from repro.obs.trace import (TraceHook, Tracer, exchange_bytes, phased_run,
+                             trace_hooks, wrap_hooks)
 
 
 @pytest.fixture(scope="module")
@@ -172,21 +175,123 @@ def test_trace_hook_counters_bit_identical(road):
     assert sum(s.args["barriers"] for s in steps) == ctx.iteration
 
 
-def test_device_loop_degrades_to_run_span(road):
-    """device_loop rejects stepwise hooks; trace_hooks hands it the
-    run-level hook instead and the run still traces."""
-    prog = SSSP(source=0)
-    policy = make_policy("hybrid")
-    tracer = Tracer()
-    hooks = trace_hooks(tracer, device_loop=True)
-    assert isinstance(hooks[0], RunTraceHook)
-    ctx = run_engine(road, prog, policy, None, hooks=hooks, device_loop=True)
-    [span] = [s for s in tracer.spans if s.name == "run"]
-    assert span.args["iterations"] == ctx.iteration
+def _profiled(tmp_path, fn):
+    """Run ``fn`` inside a ``jax.profiler`` session -> (its result, the
+    trace's events as (name, start ns, end ns, stats))."""
+    from jax.profiler import ProfileData
+
+    out_dir = str(tmp_path / "profile")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0        # host annotations, not every call
+    with jax.profiler.trace(out_dir, profiler_options=opts):
+        out = fn()
+    [path] = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                       recursive=True)
+    data = ProfileData.from_file(path)
+    return out, [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                  dict(ev.stats))
+                 for plane in data.planes for line in plane.lines
+                 for ev in line.events]
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_device_loop_records_engine_spans(road, tmp_path):
+    """A device-loop run carries no hooks; its own spans land in the
+    profiler trace, nested engine.run > {init, dispatch > loop_trace,
+    wait}, in that order."""
+    assert trace_hooks(Tracer(), device_loop=True) == ()
+    (es, iters), events = _profiled(
+        tmp_path, lambda: run_hybrid(road, SSSP(source=0)))
+    assert iters > 0
+    [run] = _named(events, "engine.run")
+    assert run[3]["engine"] == "hybrid"
+    [init] = _named(events, "engine.init")
+    [dispatch] = _named(events, "engine.dispatch")
+    [loop_trace] = _named(events, "engine.loop_trace")
+    [wait] = _named(events, "engine.wait")
+    assert all(_inside(s, run) for s in (init, dispatch, wait))
+    assert _inside(loop_trace, dispatch)
+    assert init[2] <= dispatch[1] and dispatch[2] <= wait[1]
 
     with pytest.raises(ValueError, match="device_loop"):
-        run_engine(road, prog, policy, None,
+        run_engine(road, SSSP(source=0), make_policy("hybrid"), None,
                    hooks=(TraceHook(Tracer()),), device_loop=True)
+
+
+def test_loop_trace_span_once_per_trace_of_the_loop(road, tmp_path):
+    """engine.loop_trace opens inside the traced function: one span for
+    each time JAX traces the device loop (counted by the step's own
+    trace-time side effect), none for a run that does not trace it."""
+    base = make_policy("hybrid")
+    traced = []
+
+    def step(g, prog, es, vdata):
+        traced.append(1)
+        return base.step(g, prog, es, vdata)
+
+    policy = EnginePolicy(base.name, base.init, step)
+    _, events = _profiled(tmp_path, lambda: [
+        run_engine(road, SSSP(source=0), policy, None, device_loop=True)
+        for _ in range(2)])
+    assert len(_named(events, "engine.run")) == 2
+    assert len(_named(events, "engine.loop_trace")) == len(traced) > 0
+
+
+def test_host_loop_records_run_and_init_spans(road, tmp_path):
+    """The host-driven loop has no dispatch or wait spans of its own, but
+    its run and init spans land in the trace like the device loop's."""
+    _, events = _profiled(tmp_path, lambda: run_hybrid(
+        road, SSSP(source=0), device_loop=False))
+    [run] = _named(events, "engine.run")
+    [init] = _named(events, "engine.init")
+    assert _inside(init, run)
+    assert not _named(events, "engine.dispatch")
+
+
+@pytest.mark.parametrize("engine,scopes", [
+    ("hybrid", ("exchange", "remote_delivery", "global_phase",
+                "local_phase", "message_accounting")),
+    ("bsp", ("exchange", "bsp_delivery", "bsp_compute",
+             "message_accounting"))])
+def test_lowered_step_carries_phase_scopes(road, engine, scopes):
+    """Every phase of the step is a named scope in the lowered program's
+    op names, so a device trace can attribute each op to its phase."""
+    prog = SSSP(source=0)
+    policy = make_policy(engine)
+    es = policy.init(road, prog, None)
+    text = jax.jit(lambda g, e: policy.step(g, prog, e, None)).lower(
+        road, es).as_text(debug_info=True)
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+
+
+def test_span_is_a_profiler_annotation(tmp_path):
+    """repro.obs.span and Tracer.span land in a profiler trace with their
+    args; outside a profiler session span is a plain context manager."""
+    with span("obs.outside", k=1):
+        pass
+    tracer = Tracer()
+
+    def record():
+        with span("obs.direct", k=7):
+            with tracer.span("obs.tracer", cat="test", n=3):
+                pass
+
+    _, events = _profiled(tmp_path, record)
+    [direct] = _named(events, "obs.direct")
+    [inner] = _named(events, "obs.tracer")
+    assert direct[3]["k"] == 7 and inner[3]["n"] == 3
+    assert _inside(inner, direct)
+    assert not _named(events, "obs.outside")
+    [kept] = tracer.spans
+    assert kept.name == "obs.tracer" and kept.args == {"n": 3}
 
 
 def test_disabled_tracer_contributes_nothing(road):

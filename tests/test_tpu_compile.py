@@ -7,15 +7,17 @@ programs larger than the chip's HBM — with no chip.  Each compiles one
 kernel at the widths of a scale-20 graph (2**20 rows and frontier slots),
 for one lane and for a 16-lane query batch — the fused monotone step for
 every semiring it takes, since min_mul alone carries a per-lane sender gate
-— and asserts that the kernel really lowered to Mosaic (``tpu_custom_call``)
-rather than interpret mode: tracing on the CPU backend would pick interpret
-mode, so the ``mosaic`` fixture makes ``default_interpret`` answer False.
+— and asserts that the kernel really lowered to Mosaic (``tpu_custom_call``),
+under the name a device trace shows it by, rather than interpret mode:
+tracing on the CPU backend would pick interpret mode, so the ``mosaic``
+fixture makes ``default_interpret`` answer False.
 
 The topology is described inside a fixture: only the worker that runs
 these tests loads the TPU library, and every worker collects them.
 """
 
 import os
+import re
 
 import pytest
 
@@ -71,8 +73,13 @@ def _compile(fn, *args):
         jax.config.update("jax_enable_compilation_cache", True)
 
 
-def _assert_mosaic(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_mosaic(compiled, kernel: str):
+    """The kernel lowered to Mosaic, as a custom call named ``kernel``:
+    the name a device trace shows it by."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call\(", text), \
+        kernel
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -88,7 +95,7 @@ def test_ell_spmv_compiles_for_v5e(one_chip, mosaic, lanes):
     compiled = _compile(
         lambda i, v, m, x: ell_spmv_pallas(i, v, m, x, semiring="min_add"),
         *_edges(one_chip), x)
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "ell_spmv")
 
 
 @pytest.mark.parametrize("semiring", sorted(MONOTONE_SEMIRINGS))
@@ -104,7 +111,7 @@ def test_fused_min_step_compiles_for_v5e(one_chip, mosaic, lanes, semiring):
         lambda i, v, m, x, s, xr, e: fused_min_step_pallas(
             i, v, m, x, s, xr, e, semiring=semiring),
         *_edges(one_chip), x, send, row, row)
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "min_step")
 
 
 @pytest.mark.parametrize("lanes", LANES)
@@ -119,4 +126,4 @@ def test_fused_pr_step_compiles_for_v5e(one_chip, mosaic, lanes):
         lambda i, v, m, d, s, r, e: fused_pr_step_pallas(
             i, v, m, d, s, r, e),
         *_edges(one_chip), delta, send, row, row)
-    _assert_mosaic(compiled)
+    _assert_mosaic(compiled, "pr_step")
