@@ -48,11 +48,15 @@ def run_hybrid(
 ) -> tuple[EngineState, int]:
     """Run global iterations to quiescence.
 
-    ``device_loop=True`` (default) runs the whole outer loop as one jitted
-    device-side ``lax.while_loop`` — the per-iteration ``bool(quiescent(...))``
-    host round-trip disappears and the host syncs exactly once at the end.
+    ``device_loop=True`` (default) runs the init and the whole outer loop
+    as one jitted device-side ``lax.while_loop`` — the per-iteration
+    ``bool(quiescent(...))`` host round-trip disappears and the host syncs
+    exactly once at the end.
     ``device_loop=False`` keeps the host-driven loop (useful when
-    stepping/debugging iteration by iteration).
+    stepping/debugging iteration by iteration).  Either loop is jitted once
+    per ``prog`` object and set of knobs and reused by later calls, so a
+    program object is treated as immutable once it has run: build a new
+    one for other constants, and pass per-job inputs in ``vdata``.
 
     Args:
         graph: the ``PartitionedGraph`` to iterate over.

@@ -12,8 +12,12 @@ Two lowerings of the same loop:
 * :func:`run_engine` — host-driven; checks ``quiescent`` once per step and
   calls :class:`ExecHook` methods between steps (checkpointing, failure
   detection, per-lane convergence tracking, ...).  ``device_loop=True``
-  jits the whole loop instead (one host sync at the end) when no hook
-  needs to run between steps.
+  instead jits the whole loop, with ``policy.init`` unless the caller
+  seeds the state, for runs whose hooks need nothing between steps; the
+  host syncs once, at the end.  Either way the jit is built once per
+  program object and policy (and ``max_iters`` for the loop) and reused
+  by every later run: a program object is treated as immutable once it
+  has run.
 * :func:`while_engine` — the bare ``lax.while_loop`` form, for embedding
   inside a larger jitted computation (the serving layer's full-run path).
 
@@ -22,15 +26,21 @@ modules contain step bodies, the engine modules contain configuration.
 
 Every :func:`run_engine` call opens host spans (:func:`repro.obs.span`,
 recorded only inside a ``jax.profiler`` session): ``engine.run`` around
-the call, ``engine.init`` around ``policy.init``, and on the device-loop
-path ``engine.dispatch`` (trace, lower, compile or cache load, enqueue),
+the call, ``engine.init`` around an eager ``policy.init`` (the host loop's;
+the device loop runs it inside its jit), and on the device-loop path
+``engine.dispatch`` (the enqueue of the jitted loop, and on a miss of the
+jit cache also its trace, lowering and compile or persistent-cache load;
+stat ``cached``: whether the jitted loop came from the cache),
 ``engine.loop_trace`` (inside the traced function: recorded once per
-trace of the loop) and ``engine.wait`` (the host blocked on the device).
+trace of the loop, so once per program, policy, ``max_iters`` and seeded
+or not, and again only if the argument shapes change) and ``engine.wait``
+(the host blocked on the device).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from typing import Any, Callable, Sequence
 
 import jax
@@ -50,14 +60,16 @@ class ExecContext:
     ``iteration`` mirrors ``int(es.counters.iterations)`` after every step
     and restore; ``tick`` counts host-loop trips (including trips a hook
     turned into a restore instead of a step), so failure-detection clocks
-    can advance even when no progress is made.
+    can advance even when no progress is made.  On the device loop ``es``
+    is None until the loop returns unless the caller seeded it: the jitted
+    loop runs ``policy.init`` itself.
     """
 
     graph: Any
     prog: Any
     policy: EnginePolicy | None
     vdata: Any
-    es: EngineState
+    es: EngineState | None
     iteration: int = 0
     tick: int = 0
 
@@ -111,24 +123,34 @@ def run_engine(
     ``es`` seeds the loop (default: ``policy.init``); ``jit_step``
     overrides the jitted step ``es -> es`` of the host loop (callers with a
     compile cache — the serving layer — or a shard_map step pass their
-    own).  ``device_loop=True`` lowers the whole loop into one jit of the
-    policy's step; hooks then only see ``on_start`` / ``on_exit`` (there is
-    no host boundary between steps), so it rejects hooks that override the
-    per-step methods, and it takes no ``jit_step``.
+    own).  ``device_loop=True`` lowers the whole loop, and ``policy.init``
+    when no ``es`` is given, into one jit of the policy; hooks then only
+    see ``on_start`` / ``on_exit`` (there is no host boundary between
+    steps, and ``ctx.es`` is None at ``on_start`` unless seeded), so it
+    rejects hooks that override the per-step methods, and it takes no
+    ``jit_step``.  Folding the init in keeps the device free of the eager
+    init's temporaries and programs beside the loop's own program.
+
+    The jitted loop (or default host step) is cached per ``prog`` object,
+    by identity and held weakly, per equal ``policy`` and ``max_iters``,
+    and per seeded or not: a later run with them traces nothing.  ``prog`` is
+    therefore treated as immutable once it has run; build a new program
+    object for other constants.  The graph, ``vdata`` and ``es`` are jit
+    arguments, so a new root in ``vdata`` reuses the loop.
     """
-    if device_loop and jit_step is not None:
-        raise ValueError("device_loop=True jits the policy's own step; "
-                         "jit_step is for the host loop")
+    if device_loop:
+        _check_device_loop(hooks, jit_step)
     with span("engine.run", engine=policy.name, device_loop=device_loop):
-        if es is None:
+        if es is None and not device_loop:
             with span("engine.init"):
                 es = policy.init(graph, prog, vdata)
         ctx = ExecContext(graph=graph, prog=prog, policy=policy, vdata=vdata,
-                          es=es, iteration=int(es.counters.iterations))
+                          es=es, iteration=0 if es is None
+                          else int(es.counters.iterations))
         for h in hooks:
             h.on_start(ctx)
         if device_loop:
-            _device_loop(ctx, hooks, max_iters)
+            _device_loop(ctx, max_iters)
         else:
             _host_loop(ctx, hooks, max_iters, jit_step)
         for h in hooks:
@@ -136,9 +158,30 @@ def run_engine(
     return ctx
 
 
-def _device_loop(ctx: ExecContext, hooks: Sequence[ExecHook],
-                 max_iters: int) -> None:
-    """The whole loop as one jit of the policy's step; one host sync."""
+# id(prog) -> {key: jitted function}; an entry is dropped when its
+# program is collected.  The jitted functions reach the program through a
+# weak reference only, so the cache never keeps a program alive.
+_JITS: dict[int, dict] = {}
+
+
+def _cached_jit(prog, key, build: Callable) -> tuple[Callable, bool]:
+    """The jit of ``build(prog_ref)`` for this program object and ``key``
+    -> (jitted function, whether it came from the cache)."""
+    jits = _JITS.get(id(prog))
+    if jits is None:
+        jits = _JITS[id(prog)] = {}
+        weakref.finalize(prog, _JITS.pop, id(prog), None)
+    cached = key in jits
+    if not cached:
+        jits[key] = jax.jit(build(weakref.ref(prog)))
+    return jits[key], cached
+
+
+def _check_device_loop(hooks: Sequence[ExecHook],
+                       jit_step: Callable | None) -> None:
+    if jit_step is not None:
+        raise ValueError("device_loop=True jits the policy's own step; "
+                         "jit_step is for the host loop")
     stepwise = [h for h in hooks
                 if type(h).before_step is not ExecHook.before_step
                 or type(h).after_step is not ExecHook.after_step]
@@ -147,17 +190,30 @@ def _device_loop(ctx: ExecContext, hooks: Sequence[ExecHook],
             f"device_loop=True runs with no host boundary between "
             f"steps; hooks {[type(h).__name__ for h in stepwise]} "
             f"override before_step/after_step and need the host loop")
-    prog, policy = ctx.prog, ctx.policy
+
+
+def _device_loop(ctx: ExecContext, max_iters: int) -> None:
+    """The whole loop, with ``policy.init`` where ``ctx.es`` is None, as
+    one jit of the policy; one host sync."""
+    policy, init = ctx.policy, ctx.es is None
 
     # the graph and vdata are jit *arguments*: closed over, every graph
     # array would be baked into the program as a constant
-    def loop(g, v, e):
-        with span("engine.loop_trace"):
-            return while_engine(
-                prog, lambda e_: policy.step(g, prog, e_, v), e, max_iters)
+    def build(prog_ref):
+        def loop(g, v, e):
+            prog = prog_ref()
+            with span("engine.loop_trace"):
+                if init:
+                    e = policy.init(g, prog, v)
+                return while_engine(
+                    prog, lambda e_: policy.step(g, prog, e_, v), e,
+                    max_iters)
+        return loop
 
-    with span("engine.dispatch"):
-        ctx.es = jax.jit(loop)(ctx.graph, ctx.vdata, ctx.es)
+    loop, cached = _cached_jit(ctx.prog, ("loop", policy, max_iters, init),
+                               build)
+    with span("engine.dispatch", cached=cached):
+        ctx.es = loop(ctx.graph, ctx.vdata, ctx.es)
     with span("engine.wait"):
         ctx.iteration = int(ctx.es.counters.iterations)
 
@@ -166,8 +222,10 @@ def _host_loop(ctx: ExecContext, hooks: Sequence[ExecHook], max_iters: int,
                jit_step: Callable | None) -> None:
     """One jitted step per host trip, with the hooks between steps."""
     if jit_step is None:
-        prog, policy, graph, vdata = ctx.prog, ctx.policy, ctx.graph, ctx.vdata
-        step_fn = jax.jit(lambda g, v, e: policy.step(g, prog, e, v))
+        policy, graph, vdata = ctx.policy, ctx.graph, ctx.vdata
+        step_fn, _ = _cached_jit(
+            ctx.prog, ("step", policy),
+            lambda prog_ref: lambda g, v, e: policy.step(g, prog_ref(), e, v))
         jit_step = lambda e: step_fn(graph, vdata, e)   # noqa: E731
     while (ctx.iteration < max_iters
            and not bool(quiescent(ctx.prog, ctx.es))):

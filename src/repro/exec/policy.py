@@ -15,7 +15,6 @@ constructors below.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Callable
 
 from repro.core.runtime import init_state
@@ -36,6 +35,10 @@ class EnginePolicy:
     its pseudo-superstep local phase) and must increment
     ``counters.iterations`` by exactly 1 — the driver's halt rule and
     checkpoint cadence count on it.  Both must be jittable.
+
+    Policies built by the constructors below with equal knobs compare and
+    hash equal (their ``init`` and ``step`` are :class:`Bound`), so the
+    driver builds one jit per program and policy, not one per run.
     """
 
     name: str
@@ -43,24 +46,39 @@ class EnginePolicy:
     step: Callable
 
 
+@dataclasses.dataclass(frozen=True)
+class Bound:
+    """``fn`` with keyword ``knobs`` bound: a ``functools.partial`` that
+    compares and hashes by its function and knobs (a partial does
+    neither), so two policies built with the same knobs are equal."""
+
+    fn: Callable
+    knobs: tuple[tuple[str, Any], ...]
+
+    def __call__(self, *args):
+        return self.fn(*args, **dict(self.knobs))
+
+
+def _bind(fn: Callable, **knobs: Any) -> Bound:
+    return Bound(fn, tuple(sorted(knobs.items())))
+
+
 def bsp_policy(use_ell: bool = True, collect_metrics: bool = True,
                gather_table: Callable | None = None) -> EnginePolicy:
     """Hama: one exchange + one bulk Compute() per superstep."""
     return EnginePolicy(
-        name="bsp",
-        init=lambda graph, prog, vdata: init_state(graph, prog, vdata),
-        step=partial(_bsp_step, gather_table=gather_table, use_ell=use_ell,
-                     collect_metrics=collect_metrics))
+        name="bsp", init=init_state,
+        step=_bind(bsp_superstep, gather_table=gather_table,
+                   use_ell=use_ell, collect_metrics=collect_metrics))
 
 
 def am_policy(use_ell: bool = True, collect_metrics: bool = True,
               gather_table: Callable | None = None) -> EnginePolicy:
     """AM-Hama: Hama's cadence + in-memory same-superstep local delivery."""
     return EnginePolicy(
-        name="am",
-        init=lambda graph, prog, vdata: init_state(graph, prog, vdata),
-        step=partial(_am_step, gather_table=gather_table, use_ell=use_ell,
-                     collect_metrics=collect_metrics))
+        name="am", init=init_state,
+        step=_bind(am_superstep, gather_table=gather_table,
+                   use_ell=use_ell, collect_metrics=collect_metrics))
 
 
 def hybrid_policy(use_ell: bool = True, collect_metrics: bool = True,
@@ -71,29 +89,11 @@ def hybrid_policy(use_ell: bool = True, collect_metrics: bool = True,
     to per-partition quiescence (fused Pallas local phase where eligible)."""
     return EnginePolicy(
         name="hybrid",
-        init=partial(_hybrid_init, use_ell=use_ell,
-                     collect_metrics=collect_metrics),
-        step=partial(_hybrid_step, gather_table=gather_table,
-                     max_local_steps=max_local_steps, wire_dtype=wire_dtype,
-                     use_ell=use_ell, collect_metrics=collect_metrics))
-
-
-# module-level step adapters (not closures) so a policy built twice with the
-# same knobs still hashes/compares usefully and partials stay picklable
-def _bsp_step(graph, prog, es, vdata, **kw):
-    return bsp_superstep(graph, prog, es, vdata, **kw)
-
-
-def _am_step(graph, prog, es, vdata, **kw):
-    return am_superstep(graph, prog, es, vdata, **kw)
-
-
-def _hybrid_step(graph, prog, es, vdata, **kw):
-    return hybrid_iteration(graph, prog, es, vdata, **kw)
-
-
-def _hybrid_init(graph, prog, vdata, **kw):
-    return init_hybrid(graph, prog, vdata, **kw)
+        init=_bind(init_hybrid, use_ell=use_ell,
+                   collect_metrics=collect_metrics),
+        step=_bind(hybrid_iteration, gather_table=gather_table,
+                   max_local_steps=max_local_steps, wire_dtype=wire_dtype,
+                   use_ell=use_ell, collect_metrics=collect_metrics))
 
 
 POLICIES: dict[str, Callable[..., EnginePolicy]] = {
