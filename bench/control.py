@@ -48,10 +48,12 @@ def readings(wl, seed: int, seconds: float, devices) -> dict:
     for side, runner in (("program", harness.run_hybrid),
                          ("control", control_runner)):
         with jax.default_device(devices[0]):
-            jax.block_until_ready(
-                runner(prep.graph, prep.prog, prep.kind.vdata(-1)).state)
+            jax.block_until_ready(runner(
+                prep.graph, prep.prog,
+                prep.place.replicated(prep.kind.vdata(-1))).state)
             wall, done = harness.window(prep.graph, prep.kind, prep.prog,
-                                        seconds, runner=runner)
+                                        seconds, runner=runner,
+                                        place=prep.place)
         verdict = harness.check(prep, done)
         out[side] = {"jobs": len(done), "job_s": wall / len(done),
                      "failed": verdict["failed"], **verdict["worst"]}

@@ -17,8 +17,10 @@ reduction, and ``layers``, :func:`reduce_layers`'s, over the same window:
 A TPU op event carries no scope stat.  :func:`load_events` reads each op's
 scope from its program's HLO, which the trace keeps in its
 ``/host:metadata`` plane, by the op's instruction name within the
-program's ``XLA Modules`` event.  ``bench/run.py`` reports none of these.
-Like ``bench/run.py``, this exits 2 without a TPU.
+program's ``XLA Modules`` event.  ``bench/run.py --trace 1`` reports the
+scopes of the hybrid engine's phases through :func:`scope_ms` (the
+readers ``bench/metrics/*_ms.py``); the spans and kernels only this
+prints.  Like ``bench/run.py``, this exits 2 without a TPU.
 """
 
 from __future__ import annotations
@@ -208,23 +210,16 @@ def kernel_of(ev: trace.Event) -> str | None:
     return None
 
 
-def reduce_layers(events: list[Event], window_span: str) -> dict | None:
+def reduce_layers(events: list[Event], window_span: str, devices=None
+                  ) -> dict | None:
     """``spans``, ``scopes`` and ``kernels`` (module docstring) over the
     window that the ``window_span`` host spans cover, the device times
-    per device like ``busy_s``; None where ``reduce_window`` reads
-    nothing."""
-    jobs = [ev for ev in events if ev.name == window_span
-            and not ev.plane.startswith("/device:")]
-    if not jobs:
+    per chip of ``devices`` like ``busy_s``; None where ``reduce_window``
+    reads nothing."""
+    found = trace.window_ops(events, window_span, devices)
+    if found is None:
         return None
-    lo = min(ev.start_ns for ev in jobs)
-    hi = max(ev.end_ns for ev in jobs)
-    ops_by_device: dict[str, list[Event]] = defaultdict(list)
-    for ev in events:
-        if trace.is_device_op(ev) and ev.end_ns > lo and ev.start_ns < hi:
-            ops_by_device[ev.plane].append(ev)
-    if not ops_by_device:
-        return None
+    jobs, lo, hi, ops_by_device = found
     spans: dict[str, list] = {}
     for ev in events:
         if (ev.name.startswith(ENGINE_SPANS) and lo <= ev.start_ns < hi
@@ -246,6 +241,16 @@ def reduce_layers(events: list[Event], window_span: str) -> dict | None:
                 kernels[kernel] += ns / per_device
     return {"jobs": len(jobs), "spans": spans, "scopes": dict(scopes),
             "kernels": dict(kernels)}
+
+
+def scope_ms(run: dict, scope: str):
+    """Device self milliseconds per job and chip in phase scope ``scope``
+    from a run's ``layers`` record; None where the trace has no such
+    scope."""
+    layers = run.get("layers")
+    if not layers or not layers["jobs"] or scope not in layers["scopes"]:
+        return None
+    return 1e3 * layers["scopes"][scope] / layers["jobs"]
 
 
 def main(argv=None) -> int:
@@ -273,13 +278,15 @@ def main(argv=None) -> int:
     with jax.profiler.trace(str(trace_dir), profiler_options=opts):
         with jax.default_device(devices[0]):
             wall, done = harness.window(prep.graph, prep.kind, prep.prog,
-                                        args.seconds, traced=True)
+                                        args.seconds, traced=True,
+                                        place=prep.place)
     events = load_events(str(trace_dir))
     shutil.rmtree(trace_dir, ignore_errors=True)
-    print(json.dumps({"jobs": len(done), "wall_s": wall,
-                      "window": trace.reduce_window(events, harness.JOB_SPAN),
-                      "layers": reduce_layers(events, harness.JOB_SPAN)}),
-          flush=True)
+    ids = [d.id for d in devices]
+    print(json.dumps({
+        "jobs": len(done), "wall_s": wall,
+        "window": trace.reduce_window(events, harness.JOB_SPAN, ids),
+        "layers": reduce_layers(events, harness.JOB_SPAN, ids)}), flush=True)
     return 0
 
 

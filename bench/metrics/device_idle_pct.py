@@ -1,5 +1,7 @@
 """Device (TPU v5e): the share of the traced window, from the first job's
-start to the last one's end, in which no operation ran on the device."""
+start to the last one's end, in which no operation ran on the device,
+averaged over the cell's chips: a chip that ran no operation is idle
+through the whole window."""
 
 
 def read(run: dict):

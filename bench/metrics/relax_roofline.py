@@ -2,11 +2,12 @@
 jobs' messages reach over the device's busy time.
 
 Bytes are what the messages must move: every in-memory and network
-message (``counters.mem_messages + counters.net_messages``) times the
-bytes of its value, its arc's weight and its neighbour's index.  ELL
-slots, bins and padding are never counted, so the same work reads the
-same whatever implements the relaxation.  Time is the union of device
-busy intervals over the traced window.
+message (``counters.mem_messages + counters.net_messages``, totals over
+the cell's chips) times the bytes of its value, its arc's weight and its
+neighbour's index.  ELL slots, bins and padding are never counted, so the
+same work reads the same whatever implements the relaxation.  Time is the
+union of device busy intervals over the traced window, averaged over the
+cell's chips; the peak is one chip's HBM bandwidth times the chips.
 """
 
 
@@ -17,5 +18,6 @@ def read(run: dict):
     msgs = sum(j["mem_messages"] + j["net_messages"] for j in run["jobs"])
     if msgs <= 0:
         return None
-    need_s = msgs * run["message_bytes"] / run["peaks"]["hbm_bytes_per_s"]
+    peak = run["peaks"]["hbm_bytes_per_s"] * run.get("chips", 1)
+    need_s = msgs * run["message_bytes"] / peak
     return 100.0 * need_s / trace["busy_s"]

@@ -97,3 +97,33 @@ def test_roots_come_from_the_largest_component():
     roots = sample_roots(e, 8, 4, 2**31 + 1)
     assert len(set(roots.tolist())) == 4 and np.all(roots < 6)
     assert np.array_equal(roots, sample_roots(e, 8, 4, 2**31 + 1))
+
+
+def test_graph_seed_gives_every_run_one_graph_and_its_own_weights():
+    import jax
+
+    from bench import harness
+    from bench.tests import tiny
+    assert "graph_seed" in harness.load_workload(tiny.CELL).config[
+        "generator"]
+    wl = tiny.tiny_workload(tiny.CELL)
+    cfg = {**wl.config, "generator": {**wl.config["generator"],
+                                      "graph_seed": 1}}
+    runs = []
+    for seed in (2**31 + 3, 2**31 + 5):
+        e, w, n = harness.generate(cfg, seed)
+        g, _ = harness.build(cfg, e, w, n, seed,
+                             harness.Placement(tuple(jax.devices()[:1])))
+        leaves, tree = jax.tree.flatten(g)
+        runs.append((e, w, tree, [(x.shape, x.dtype) for x in leaves]))
+    (e1, w1, t1, s1), (e2, w2, t2, s2) = runs
+    assert np.array_equal(e1, e2) and t1 == t2 and s1 == s2
+    assert not np.array_equal(w1, w2)
+    half = len(e1) // 2
+    for w in (w1, w2):
+        assert w.dtype == np.float32 and np.all((w >= 0) & (w < 1))
+        assert np.array_equal(w[:half], w[half:])
+    # without a graph_seed the run's seed draws structure and weights
+    e, w, _ = harness.generate(wl.config, 2**31 + 3)
+    ref = kronecker_edges(7, 16, (0.57, 0.19, 0.19, 0.05), 2**31 + 3)
+    assert np.array_equal(e, ref[0]) and np.array_equal(w, ref[1])

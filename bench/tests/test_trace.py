@@ -5,7 +5,7 @@ from pathlib import Path
 
 import pytest
 
-from bench.trace import Event, reduce_window, union
+from bench.trace import Event, device_id, reduce_window, union
 
 DEV = "/device:TPU:0"
 HOST = "/host:CPU"
@@ -77,6 +77,44 @@ def test_busy_is_averaged_over_devices():
     assert reduce_window(ev, "bench.job")["busy_s"] == pytest.approx(0.07)
 
 
+def _four_chips(idle: int):
+    """One 100 ms job; chips 0-3 each busy all through, but chip ``idle``,
+    which runs no op."""
+    ms = 1_000_000
+    ev = [Event(HOST, "python", "bench.job", 0, 100 * ms)]
+    ev += [Event(f"/device:TPU:{d}", "XLA Ops", "fold", 0, 100 * ms)
+           for d in range(4) if d != idle]
+    return ev
+
+
+@pytest.mark.parametrize("idle", [0, 3])
+def test_chip_that_ran_no_op_counts_as_idle(idle):
+    from bench.metrics.device_idle_pct import read
+    r = reduce_window(_four_chips(idle), "bench.job", [0, 1, 2, 3])
+    assert r["devices"] == 4
+    assert r["busy_s"] == pytest.approx(0.075)
+    assert read({"trace": r}) >= 25.0
+    # without the cell's chips, only the planes that ran an op count
+    assert reduce_window(_four_chips(idle), "bench.job")["devices"] == 3
+
+
+def test_one_chip_cell_reads_only_its_own_plane():
+    ms = 1_000_000
+    ev = _four_chips(idle=3) + [
+        Event("/device:TPU:3", "XLA Ops", "fold", 0, 20 * ms)]
+    assert reduce_window(ev, "bench.job", [3])["busy_s"] == \
+        pytest.approx(0.02)
+    # a chip of the cell that ran nothing in the window reads nothing
+    assert reduce_window(_four_chips(idle=3), "bench.job", [3]) is None
+
+
+def test_device_id_of_a_plane():
+    assert device_id("/device:TPU:3") == 3
+    assert device_id("/device:TPU:12") == 12
+    assert device_id("/host:CPU") is None
+    assert device_id("/device:TPU:0 SparseCore") is None
+
+
 def test_recorded_tpu_trace():
     """An excerpt of a traced run on one TPU v5e: its device ops lie on
     the TPU plane's ops line and every reading stays within the window."""
@@ -87,3 +125,12 @@ def test_recorded_tpu_trace():
     assert 0 < r["busy_s"] <= r["window_s"]
     assert sum(s for _, s in r["device_ops"]) >= r["busy_s"] * 0.999
     assert all(s <= r["window_s"] for _, s in r["idle_gaps"])
+
+
+def test_recorded_tpu_trace_reads_the_same_for_its_one_chip():
+    """Given the cell's one chip, the reduction is the one without: the
+    same readings, bit for bit."""
+    path = Path(__file__).parent / "data" / "tpu_trace_excerpt.json"
+    ev = [Event(*row) for row in json.loads(path.read_text())]
+    assert reduce_window(ev, "bench.job", [0]) == reduce_window(
+        ev, "bench.job")

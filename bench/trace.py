@@ -6,7 +6,9 @@ window spanned by the host spans named ``window_span`` (the benchmark's
 jobs):
 
 * ``busy_s`` — the union of the intervals in which an operation ran on a
-  device, averaged over the devices traced;
+  device, averaged over the cell's chips (given by device id; a chip that
+  ran no operation counts as idle), or over the devices traced where no
+  ids are given;
 * ``window_s`` — from the first job span's start to the last one's end;
 * ``device_ops`` — the device operations that took most time, by name,
   each counted by its self time (a loop op without its body's ops);
@@ -23,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import re
 from collections import defaultdict
 
 #: the device planes' line that holds one event per executed operation
@@ -58,6 +61,38 @@ def load_events(trace_dir: str) -> list[Event]:
 
 def is_device_op(ev: Event) -> bool:
     return ev.plane.startswith("/device:") and ev.line == DEVICE_OPS_LINE
+
+
+def device_id(plane: str) -> int | None:
+    """The device id in a device plane's name (``/device:TPU:3`` -> 3)."""
+    m = re.fullmatch(r"/device:[^:]+:(\d+)", plane)
+    return int(m.group(1)) if m else None
+
+
+def window_ops(events: list[Event], window_span: str, devices=None):
+    """The window the ``window_span`` host spans cover and the device ops
+    inside it -> (job spans, lo, hi, {device: [op]}), or None where the
+    trace holds no such span or no op inside it.  With ``devices`` (ids)
+    the keys are those ids, each chip present even where it ran no op, and
+    a plane of any other device is left out; without, the keys are the
+    planes that ran an op."""
+    jobs = [ev for ev in events if ev.name == window_span
+            and not ev.plane.startswith("/device:")]
+    if not jobs:
+        return None
+    lo = min(ev.start_ns for ev in jobs)
+    hi = max(ev.end_ns for ev in jobs)
+    ops: dict = defaultdict(list)
+    for ev in events:
+        if is_device_op(ev) and ev.end_ns > lo and ev.start_ns < hi:
+            key = ev.plane if devices is None else device_id(ev.plane)
+            if devices is None or key in devices:
+                ops[key].append(ev)
+    if not ops:
+        return None
+    if devices is not None:
+        ops = {d: ops.get(d, []) for d in devices}
+    return jobs, lo, hi, ops
 
 
 def union(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -100,30 +135,22 @@ def _host_name(spans: list[Event], t: float) -> str:
     return min(inside, key=lambda ev: ev.end_ns - ev.start_ns).name
 
 
-def reduce_window(events: list[Event], window_span: str, top: int = 10
-                  ) -> dict | None:
+def reduce_window(events: list[Event], window_span: str, devices=None,
+                  top: int = 10) -> dict | None:
     """Device busy time, top operations and idle gaps over the window the
-    ``window_span`` host spans cover; None when the trace holds no such
-    span or no device operation inside it."""
-    jobs = [ev for ev in events if ev.name == window_span
-            and not ev.plane.startswith("/device:")]
-    if not jobs:
+    ``window_span`` host spans cover, on the chips ``devices`` (ids; every
+    device that ran an op where None); None when the trace holds no such
+    span or no device operation of those chips inside it."""
+    found = window_ops(events, window_span, devices)
+    if found is None:
         return None
-    lo = min(ev.start_ns for ev in jobs)
-    hi = max(ev.end_ns for ev in jobs)
-    ops_by_device: dict[str, list[Event]] = defaultdict(list)
-    for ev in events:
-        if is_device_op(ev) and ev.end_ns > lo and ev.start_ns < hi:
-            ops_by_device[ev.plane].append(ev)
-    if not ops_by_device:
-        return None
-    by_device = {dev: [(ev.start_ns, ev.end_ns) for ev in evs]
-                 for dev, evs in ops_by_device.items()}
+    jobs, lo, hi, ops_by_device = found
     op_ns: dict[str, float] = defaultdict(float)
     for evs in ops_by_device.values():
         for name, ns in self_times(evs, lo, hi):
             op_ns[name] += ns
-    busy = {dev: union(iv, lo, hi) for dev, iv in by_device.items()}
+    busy = {dev: union([(ev.start_ns, ev.end_ns) for ev in evs], lo, hi)
+            for dev, evs in ops_by_device.items()}
     busy_ns = sum(sum(e - s for s, e in iv) for iv in busy.values())
     busy_ns /= len(busy)
 
