@@ -1,7 +1,7 @@
 """Chip smoke test: the hybrid engine's main path, once, on one TPU.
 
     python chip_smoke.py [--seed N]          # one chip
-    python chip_smoke.py --four-chips        # the 4-device shard_map step
+    python chip_smoke.py --four-chips        # run_hybrid over a 2x2 mesh
 
 On one chip it builds a Graph500-style R-MAT graph (scale 20, edge factor
 16, initiator 0.57/0.19/0.19) with seeded uniform weights and a hash
@@ -16,9 +16,10 @@ partition, then drives the entry points a user calls:
 * the compiled hybrid step, which must hold a Mosaic kernel
   (``tpu_custom_call``).
 
-``--four-chips`` runs only the distributed step (``make_dist_hybrid_step``)
-on a 2x2 mesh and compares it with ``run_hybrid`` on one device: fixed
-point, iteration count and every paper counter bit-exact.
+``--four-chips`` runs only ``run_hybrid`` on the graph placed over a 2x2
+mesh (its device loop one shard_map over the mesh) and compares it with
+``run_hybrid`` on one device: fixed point, iteration count and every paper
+counter bit-exact.
 
 The last line of standard output is one JSON object naming the device.
 Without a TPU the script exits non-zero before any phase runs; any phase
@@ -231,17 +232,15 @@ def phase_kernel_path(graph, source: int) -> dict:
 
 
 def phase_four_chips(graph, source: int, mesh) -> dict:
-    """SSSP through the shard_map step on ``mesh`` against ``run_hybrid`` on
-    one device: state, iterations and every counter bit-exact, with the
+    """SSSP through ``run_hybrid`` on the graph placed over ``mesh`` (its
+    device loop runs one shard_map over the mesh) against ``run_hybrid``
+    on one device: state, iterations and every counter bit-exact, with the
     graph and state spread over every device of the mesh."""
     import jax
     from jax.sharding import NamedSharding
     from repro.core import run_hybrid
     from repro.core.apps import SSSP
-    from repro.core.distributed import (_es_specs, make_dist_hybrid_step,
-                                        shard0_specs)
-    from repro.core.engine_hybrid import init_hybrid
-    from repro.core.runtime import quiescent
+    from repro.core.distributed import shard0_specs
 
     prog = SSSP(source=int(source))
     t0 = time.perf_counter()
@@ -249,20 +248,11 @@ def phase_four_chips(graph, source: int, mesh) -> dict:
     jax.block_until_ready(es_ref.state)
     wall_ref = time.perf_counter() - t0
 
-    axes = tuple(mesh.axis_names)
-    step = make_dist_hybrid_step(prog, mesh, axes=axes)
-    es = init_hybrid(graph, prog, None)
-    gs = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                      shard0_specs(graph, axes))
-    ess = jax.tree.map(lambda s: NamedSharding(mesh, s), _es_specs(es, axes))
-    graph_d = jax.device_put(graph, gs)
-    es_d = jax.device_put(es, ess)
-    jitted = jax.jit(step, in_shardings=(gs, ess))
+    graph_d = jax.device_put(graph, jax.tree.map(
+        lambda s: NamedSharding(mesh, s),
+        shard0_specs(graph, tuple(mesh.axis_names))))
     t0 = time.perf_counter()
-    iters = 0
-    while not bool(quiescent(prog, es_d)) and iters < 10_000:
-        es_d = jitted(graph_d, es_d)
-        iters += 1
+    es_d, iters = run_hybrid(graph_d, prog)
     jax.block_until_ready(es_d.state)
     wall = time.perf_counter() - t0
 
@@ -338,7 +328,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the 4-device shard_map step and its "
+                    help="run only run_hybrid over a 2x2 mesh and its "
                          "one-device comparison")
     args = ap.parse_args(argv)
 

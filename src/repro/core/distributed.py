@@ -5,31 +5,116 @@ This is the faithful lowering of the paper's architecture: the local phase's
 ``lax.while_loop`` runs *per device with no collectives in its body* — every
 device truly iterates pseudo-supersteps to its own partition's convergence,
 decoupled from the others — and the only cross-device communication is the
-once-per-global-iteration export all-gather (+ the quiescence psum the
-paper's master performs over worker responses).
+once-per-global-iteration export all-gather, the psum of the iteration's
+message counters, and the quiescence vote the paper's master collects from
+its workers.
+
+:func:`block_policy` is the per-block body; the executor's device loop
+(:func:`repro.exec.driver.run_engine`, the path ``run_hybrid`` takes) runs
+it through :func:`shard_loop` when the graph lies on a mesh, and
+:func:`make_dist_hybrid_step` wraps one step of it for callers that step
+by hand.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.exec.iteration import hybrid_iteration
 from repro.core.graph import PartitionedGraph
 from repro.core.runtime import Counters, EngineState
 from repro.core.vertex_program import VertexProgram
+from repro.exec.policy import EnginePolicy, hybrid_policy
 
 AXES = ("data", "model")
+#: the counters each block counts for its own partitions, summed over the
+#: blocks; ``iterations`` is the same on every block and
+#: ``pseudo_supersteps`` is per partition, sharded like the partitions
+SUMMED = ("net_messages", "net_local_messages", "mem_messages")
 
 
 def shard0_specs(tree, axes) -> Any:
     """Every array leaf sharded on dim 0 over the flattened device axes."""
     return jax.tree.map(lambda l: P(axes), tree)
+
+
+def check_blocks(graph: PartitionedGraph, devices: int) -> None:
+    """Raise unless the graph splits into one equal block per device."""
+    if graph.n_partitions % devices or graph.n_blocks % devices:
+        raise ValueError(
+            f"distributed step needs n_partitions ({graph.n_partitions})"
+            f" and n_blocks ({graph.n_blocks}) divisible by the device "
+            f"count ({devices}); build with edge_blocks={devices} (or a "
+            f"multiple)")
+
+
+def sum_counters(c: Counters, axes, since: Counters | None = None
+                 ) -> Counters:
+    """The master's aggregation of the paper's message counters: each
+    block's counts since ``since`` (all of them where None) summed over
+    ``axes`` in one ``psum``, added to the replicated totals of
+    ``since``."""
+    with jax.named_scope("message_accounting"):
+        base = [0 if since is None else getattr(since, f) for f in SUMMED]
+        total = jax.lax.psum(tuple(getattr(c, f) - b
+                                   for f, b in zip(SUMMED, base)), axes)
+        return dataclasses.replace(c, **{f: b + t for f, b, t
+                                         in zip(SUMMED, base, total)})
+
+
+def block_policy(policy: EnginePolicy, axes) -> EnginePolicy:
+    """``policy`` as the body of one block of a shard_map over ``axes``:
+    its init and step run on the block's partitions, the step's exchange
+    shares the export table by ``all_gather`` (the policy's
+    ``gather_table``), and the message counters are summed over the
+    blocks after the init and after each step (:func:`sum_counters`)."""
+    def gather_table(x):
+        # local (Pb, X, ...) -> global (P, X, ...): the one exchange
+        return jax.lax.all_gather(x, axes, axis=0, tiled=True)
+
+    inner = policy.step.with_knobs(gather_table=gather_table)
+
+    def init(graph, prog, vdata):
+        es = policy.init(graph, prog, vdata)
+        return dataclasses.replace(es, counters=sum_counters(es.counters,
+                                                             axes))
+
+    def step(graph, prog, es, vdata):
+        c0 = es.counters            # replicated totals from last iteration
+        es = inner(graph, prog, es, vdata)
+        return dataclasses.replace(
+            es, counters=sum_counters(es.counters, axes, since=c0))
+
+    return EnginePolicy(policy.name, init, step)
+
+
+def shard_loop(loop: Callable, policy: EnginePolicy, prog: VertexProgram,
+               mesh: Mesh, graph: PartitionedGraph, vdata: Any,
+               es: EngineState | None) -> EngineState:
+    """``loop(policy, graph, vdata, es, vote)``, the executor's device
+    loop, run as one shard_map over ``mesh``: the graph and the state
+    partition-sharded on dim 0, ``vdata`` replicated, ``policy`` as
+    :func:`block_policy` makes it, and ``vote`` reducing each block's "not
+    quiescent" flag over the mesh, so every block halts at the same
+    iteration.  ``es`` None runs the init inside the shard_map too."""
+    axes = tuple(mesh.axis_names)
+    check_blocks(graph, mesh.size)
+    block = block_policy(policy, axes)
+    specs = _es_specs(es if es is not None else jax.eval_shape(
+        lambda g, v: policy.init(g, prog, v), graph, vdata), axes)
+
+    def vote(busy):
+        return jax.lax.psum(busy.astype(jnp.int32), axes) > 0
+
+    return jax.shard_map(
+        lambda g, v, e: loop(block, g, v, e, vote), mesh=mesh,
+        in_specs=(shard0_specs(graph, axes), P(),
+                  P() if es is None else specs),
+        out_specs=specs, check_vma=False)(graph, vdata, es)
 
 
 def make_dist_hybrid_step(prog: VertexProgram, mesh: Mesh,
@@ -38,7 +123,9 @@ def make_dist_hybrid_step(prog: VertexProgram, mesh: Mesh,
                           wire_dtype=None, use_ell: bool = True,
                           collect_metrics: bool = True, tracer=None):
     """Returns a jittable step: (graph, es) -> es, running one global
-    iteration on a mesh where dim 0 of every array is the partition axis.
+    iteration on a mesh where dim 0 of every array is the partition axis:
+    one step of :func:`block_policy`'s hybrid body, the body
+    ``run_hybrid``'s device loop runs on a graph placed over a mesh.
     ``wire_dtype=jnp.bfloat16`` halves exchange bytes (§Perf);
     ``use_ell``/``collect_metrics`` select the kernel-backed local phase
     (the ELL tiles shard on dim 0 like every other partition-major array).
@@ -57,43 +144,17 @@ def make_dist_hybrid_step(prog: VertexProgram, mesh: Mesh,
     (honest timing) and is *not* meant to be re-jitted by the caller;
     ``tracer=None`` (the default) returns the bare jittable step with no
     observability import at all."""
-
-    def gather_table(x):
-        # local (Pb, X, ...) -> global (P, X, ...): the one exchange
-        return jax.lax.all_gather(x, axes, axis=0, tiled=True)
-
-    def local_step(graph: PartitionedGraph, es: EngineState) -> EngineState:
-        c0 = es.counters            # replicated totals from last iteration
-        es = hybrid_iteration(graph, prog, es, vdata,
-                              gather_table=gather_table,
-                              max_local_steps=max_local_steps,
-                              wire_dtype=wire_dtype, use_ell=use_ell,
-                              collect_metrics=collect_metrics)
-        # master-side aggregation of the paper's metrics: psum only THIS
-        # iteration's per-device delta (one collective, outside the
-        # pseudo-superstep loop), keeping the running totals replicated.
-        c = es.counters
-        agg = dataclasses.replace(
-            c,
-            net_messages=c0.net_messages + jax.lax.psum(
-                c.net_messages - c0.net_messages, axes),
-            net_local_messages=c0.net_local_messages + jax.lax.psum(
-                c.net_local_messages - c0.net_local_messages, axes),
-            mem_messages=c0.mem_messages + jax.lax.psum(
-                c.mem_messages - c0.mem_messages, axes))
-        return dataclasses.replace(es, counters=agg)
+    block = block_policy(hybrid_policy(
+        use_ell=use_ell, collect_metrics=collect_metrics,
+        max_local_steps=max_local_steps, wire_dtype=wire_dtype), axes)
 
     def step(graph, es):
-        d = mesh.size
-        if graph.n_partitions % d or graph.n_blocks % d:
-            raise ValueError(
-                f"distributed step needs n_partitions ({graph.n_partitions})"
-                f" and n_blocks ({graph.n_blocks}) divisible by the device "
-                f"count ({d}); build with edge_blocks={d} (or a multiple)")
-        in_specs = (shard0_specs(graph, axes), _es_specs(es, axes))
-        out_specs = _es_specs(es, axes)
-        return jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)(graph, es)
+        check_blocks(graph, mesh.size)
+        specs = _es_specs(es, axes)
+        return jax.shard_map(
+            lambda g, e: block.step(g, prog, e, vdata), mesh=mesh,
+            in_specs=(shard0_specs(graph, axes), specs), out_specs=specs,
+            check_vma=False)(graph, es)
 
     if tracer is not None:
         from repro.obs.trace import traced_dist_step   # lazy: opt-in only

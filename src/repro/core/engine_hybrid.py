@@ -59,7 +59,13 @@ def run_hybrid(
     one for other constants, and pass per-job inputs in ``vdata``.
 
     Args:
-        graph: the ``PartitionedGraph`` to iterate over.
+        graph: the ``PartitionedGraph`` to iterate over.  Placed over a
+            mesh of several devices (each array leaf a ``NamedSharding``
+            on dim 0, as ``repro.core.distributed.shard0_specs`` gives),
+            the device loop runs as one shard_map over that mesh: one
+            block of partitions a device, the export table shared by
+            all-gather, the counters summed by psum; the results equal
+            the one-device run's.
         prog: the ``VertexProgram``; its channels decide kernel dispatch
             (semiring / ``fused_kernel`` / lane width).
         vdata: optional per-run auxiliary arrays handed to the program's

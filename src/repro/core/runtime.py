@@ -86,7 +86,8 @@ def init_state(graph: PartitionedGraph, prog: VertexProgram, vdata: Any) -> Engi
     state, out, send, active = prog.init(graph.vertex_gid, graph.vertex_mask, vdata)
     send = jnp.logical_and(send, graph.vertex_mask)
     active = jnp.logical_and(active, graph.vertex_mask)
-    p, vp, h = graph.n_partitions, graph.vp, graph.hp
+    # the partitions this call holds: all of them, or one shard_map block's
+    p, vp, h = graph.vertex_gid.shape[0], graph.vp, graph.hp
     halo_out = jax.tree.map(
         lambda l: jnp.zeros((p, h) + l.shape[2:], l.dtype), out)
     return EngineState(

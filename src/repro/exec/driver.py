@@ -14,10 +14,12 @@ Two lowerings of the same loop:
   detection, per-lane convergence tracking, ...).  ``device_loop=True``
   instead jits the whole loop, with ``policy.init`` unless the caller
   seeds the state, for runs whose hooks need nothing between steps; the
-  host syncs once, at the end.  Either way the jit is built once per
-  program object and policy (and ``max_iters`` for the loop) and reused
-  by every later run: a program object is treated as immutable once it
-  has run.
+  host syncs once, at the end.  On a graph whose leaves lie on a mesh of
+  more than one device the device loop is one shard_map over that mesh
+  (:func:`repro.core.distributed.shard_loop`).  Either way the jit is
+  built once per program object and policy (and ``max_iters`` and the
+  mesh for the loop) and reused by every later run: a program object is
+  treated as immutable once it has run.
 * :func:`while_engine` — the bare ``lax.while_loop`` form, for embedding
   inside a larger jitted computation (the serving layer's full-run path).
 
@@ -30,16 +32,18 @@ the call, ``engine.init`` around an eager ``policy.init`` (the host loop's;
 the device loop runs it inside its jit), and on the device-loop path
 ``engine.dispatch`` (the enqueue of the jitted loop, and on a miss of the
 jit cache also its trace, lowering and compile or persistent-cache load;
-stat ``cached``: whether the jitted loop came from the cache),
+stat ``cached``: whether the jitted loop came from the cache; stat
+``chips``: the devices the loop runs on, the mesh's or 1),
 ``engine.loop_trace`` (inside the traced function: recorded once per
-trace of the loop, so once per program, policy, ``max_iters`` and seeded
-or not, and again only if the argument shapes change) and ``engine.wait``
-(the host blocked on the device).
+trace of the loop, so once per program, policy, ``max_iters``, mesh and
+seeded or not, and again only if the argument shapes change) and
+``engine.wait`` (the host blocked on the device).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 from typing import Any, Callable, Sequence
 
@@ -94,13 +98,18 @@ class ExecHook:
     def on_exit(self, ctx: ExecContext) -> None: ...
 
 
-def while_engine(prog, step: Callable, es: EngineState, max_iters: int):
+def while_engine(prog, step: Callable, es: EngineState, max_iters: int,
+                 vote: Callable | None = None):
     """The device-side loop body: iterate ``step`` (``es -> es``) until
     quiescence or ``max_iters``, as a ``lax.while_loop``.  Not jitted here
-    — embed it in whatever jit owns the surrounding computation."""
+    — embed it in whatever jit owns the surrounding computation.  Inside
+    a shard_map block ``vote`` turns the block's "not quiescent" flag into
+    the mesh's (one reduction over its axes)."""
     def cond(e):
-        return jnp.logical_and(jnp.logical_not(quiescent(prog, e)),
-                               e.counters.iterations < max_iters)
+        busy = jnp.logical_not(quiescent(prog, e))
+        if vote is not None:
+            busy = vote(busy)
+        return jnp.logical_and(busy, e.counters.iterations < max_iters)
 
     return jax.lax.while_loop(cond, step, es)
 
@@ -131,9 +140,17 @@ def run_engine(
     ``jit_step``.  Folding the init in keeps the device free of the eager
     init's temporaries and programs beside the loop's own program.
 
+    On a graph placed over a mesh (its leaves' ``NamedSharding`` over
+    more than one device, dim 0 partition-sharded as
+    :func:`repro.core.distributed.shard0_specs` lays it) the device loop
+    runs the init and every step as one shard_map over that mesh, with
+    ``vdata`` replicated; results and counters equal the one-device
+    run's.
+
     The jitted loop (or default host step) is cached per ``prog`` object,
     by identity and held weakly, per equal ``policy`` and ``max_iters``,
-    and per seeded or not: a later run with them traces nothing.  ``prog`` is
+    per mesh, and per seeded or not: a later run with them traces
+    nothing.  ``prog`` is
     therefore treated as immutable once it has run; build a new program
     object for other constants.  The graph, ``vdata`` and ``es`` are jit
     arguments, so a new root in ``vdata`` reuses the loop.
@@ -192,27 +209,61 @@ def _check_device_loop(hooks: Sequence[ExecHook],
             f"override before_step/after_step and need the host loop")
 
 
-def _device_loop(ctx: ExecContext, max_iters: int) -> None:
-    """The whole loop, with ``policy.init`` where ``ctx.es`` is None, as
-    one jit of the policy; one host sync."""
-    policy, init = ctx.policy, ctx.es is None
+def graph_mesh(graph):
+    """The mesh of more than one device that the graph's leaves lie on
+    (their ``NamedSharding``), or None for a graph on one device."""
+    for leaf in jax.tree.leaves(graph):
+        sharding = getattr(leaf, "sharding", None)
+        mesh = getattr(sharding, "mesh", None)
+        if mesh is not None and mesh.size > 1:
+            return mesh
+    return None
 
+
+def _loop(prog, max_iters: int, policy: EnginePolicy, g, v, e,
+          vote: Callable | None = None) -> EngineState:
+    """The device loop's body: ``policy.init`` where ``e`` is None, then
+    :func:`while_engine` over ``policy.step``."""
+    if e is None:
+        e = policy.init(g, prog, v)
+    return while_engine(prog, lambda e_: policy.step(g, prog, e_, v), e,
+                        max_iters, vote=vote)
+
+
+def device_loop_jit(prog, policy: EnginePolicy, max_iters: int, init: bool,
+                    mesh=None) -> tuple[Callable, bool]:
+    """The jitted device loop ``(graph, vdata, es) -> es`` for this
+    program object, policy, ``max_iters``, whether it runs the init, and
+    the mesh the graph lies on (None: one device) -> (jitted function,
+    whether it came from the cache).  On a mesh the loop is one shard_map
+    over it (:func:`repro.core.distributed.shard_loop`), so every Pallas
+    call runs on one block of partitions."""
     # the graph and vdata are jit *arguments*: closed over, every graph
     # array would be baked into the program as a constant
     def build(prog_ref):
         def loop(g, v, e):
             prog = prog_ref()
+            body = functools.partial(_loop, prog, max_iters)
             with span("engine.loop_trace"):
-                if init:
-                    e = policy.init(g, prog, v)
-                return while_engine(
-                    prog, lambda e_: policy.step(g, prog, e_, v), e,
-                    max_iters)
+                if mesh is None:
+                    return body(policy, g, v, e)
+                from repro.core.distributed import shard_loop
+                return shard_loop(body, policy, prog, mesh, g, v, e)
         return loop
 
-    loop, cached = _cached_jit(ctx.prog, ("loop", policy, max_iters, init),
-                               build)
-    with span("engine.dispatch", cached=cached):
+    key = ("loop", policy, max_iters, init)
+    return _cached_jit(prog, key if mesh is None else key + (mesh,), build)
+
+
+def _device_loop(ctx: ExecContext, max_iters: int) -> None:
+    """The whole loop, with ``policy.init`` where ``ctx.es`` is None, as
+    one jit of the policy (over the graph's mesh where it lies on one);
+    one host sync."""
+    mesh = graph_mesh(ctx.graph)
+    loop, cached = device_loop_jit(ctx.prog, ctx.policy, max_iters,
+                                   ctx.es is None, mesh)
+    with span("engine.dispatch", cached=cached,
+              chips=1 if mesh is None else mesh.size):
         ctx.es = loop(ctx.graph, ctx.vdata, ctx.es)
     with span("engine.wait"):
         ctx.iteration = int(ctx.es.counters.iterations)

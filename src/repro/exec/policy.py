@@ -58,6 +58,16 @@ class Bound:
     def __call__(self, *args):
         return self.fn(*args, **dict(self.knobs))
 
+    def with_knobs(self, **knobs: Any) -> "Bound":
+        """The same function with ``knobs`` replacing its own; each must
+        be one it already binds."""
+        own = dict(self.knobs)
+        unknown = sorted(set(knobs) - set(own))
+        if unknown:
+            raise TypeError(f"{getattr(self.fn, '__name__', self.fn)} "
+                            f"binds no knob {unknown}")
+        return _bind(self.fn, **{**own, **knobs})
+
 
 def _bind(fn: Callable, **knobs: Any) -> Bound:
     return Bound(fn, tuple(sorted(knobs.items())))

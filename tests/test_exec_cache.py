@@ -181,3 +181,16 @@ def test_dropped_program_is_collected_with_its_entry(road):
     gc.collect()
     assert ref() is None
     assert key not in driver._JITS
+
+
+def test_with_knobs_rebinds_only_the_knobs_a_step_has():
+    """The mesh path rebinds a policy's ``gather_table``: the result
+    equals a policy built with that knob, and a knob the step does not
+    bind is refused."""
+    base = hybrid_policy()
+    gather = jnp.asarray
+    assert base.step.with_knobs(gather_table=gather) == \
+        hybrid_policy(gather_table=gather).step
+    assert base.step.with_knobs(gather_table=None) == base.step
+    with pytest.raises(TypeError, match="no_such_knob"):
+        base.step.with_knobs(no_such_knob=1)

@@ -127,3 +127,45 @@ def test_fused_pr_step_compiles_for_v5e(one_chip, mosaic, lanes):
             i, v, m, d, s, r, e),
         *_edges(one_chip), delta, send, row, row)
     _assert_mosaic(compiled, "pr_step")
+
+
+def test_run_hybrid_mesh_loop_compiles_for_v5e_2x2(topo, mosaic):
+    """``run_hybrid``'s device loop on a graph placed over a 2x2 mesh: one
+    shard_map over the mesh, so the chip's partitioner meets no Pallas
+    call it would have to split (jit's automatic partitioning refuses
+    Mosaic kernels).  The kernels lower to Mosaic on every block, and the
+    export table crosses chips by all-gather."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import build_partitioned_graph, hash_partition
+    from repro.core.apps import MultiSourceMonotone
+    from repro.core.distributed import shard0_specs
+    from repro.data.graphs import rmat_graph
+    from repro.exec import driver
+    from repro.exec.policy import hybrid_policy
+
+    edges, n = rmat_graph(1 << 10, avg_degree=16, seed=1)
+    w = np.random.default_rng(1).random(len(edges), dtype=np.float32)
+    graph = build_partitioned_graph(edges, n, hash_partition(n, 8, seed=1),
+                                    weights=w, ell_base_slices=16,
+                                    edge_blocks=4)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             shard0_specs(graph, mesh.axis_names))
+    g = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                       sharding=s),
+                     graph, shardings)
+    v = {"sources": jax.ShapeDtypeStruct(
+        (1,), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec()))}
+    assert driver.graph_mesh(g) is mesh
+    prog = MultiSourceMonotone(lanes=1, semiring="min_add")
+    loop, _ = driver.device_loop_jit(prog, hybrid_policy(), 100_000, True,
+                                     mesh)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = loop.lower(g, v, None).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    _assert_mosaic(compiled, "min_step")
+    assert "all-gather" in compiled.as_text()
