@@ -121,7 +121,7 @@ def _fused_pr_step_fn(graph, prog):
     has/running/export bookkeeping."""
     from repro.core.engine_hybrid import fused_step_fn
 
-    kstep, _, _ = fused_step_fn(graph, prog, "pr_step", graph.n_partitions)
+    kstep = fused_step_fn(graph, prog, "pr_step", graph.n_partitions)
 
     def step(rank, delta, send, eo, esend):
         rank_n, d_in, send_n = kstep(rank, delta, send)
@@ -138,7 +138,7 @@ def _fused_min_step_fn(graph, prog):
     collect_metrics=False bookkeeping of `_fused_min_local_phase`."""
     from repro.core.engine_hybrid import fused_step_fn
 
-    kstep, _, _ = fused_step_fn(graph, prog, "min_step", graph.n_partitions)
+    kstep = fused_step_fn(graph, prog, "min_step", graph.n_partitions)
 
     def step(x, send, eo, esend):
         x_n, d_n, send_n = kstep(x, send)

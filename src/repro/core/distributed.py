@@ -212,6 +212,7 @@ def block_graph_shapes(n_partitions: int, vp: int, ep: int, xp: int, hp: int,
         vertex_mask=f((n_partitions, vp), b),
         is_boundary=f((n_partitions, vp), b),
         out_degree=f((n_partitions, vp), i32),
+        local_out_degree=f((n_partitions, vp), i32),
         edge_src=f((nb, ep), i32),
         edge_dst=f((nb, ep), i32),
         edge_w=f((nb, ep), f32),

@@ -219,6 +219,9 @@ class PartitionedGraph:
     vertex_mask: jax.Array      # (P, Vp) bool
     is_boundary: jax.Array      # (P, Vp) bool — has a remote in-edge
     out_degree: jax.Array       # (P, Vp) int32 — global out-degree
+    # same-partition out-degree: the vertex's valid source slots over every
+    # bin of ``local_ell`` (the fused local phases' raw message count)
+    local_out_degree: jax.Array  # (P, Vp) int32
     # ---- in-edges, block-ragged, sorted by destination slot per span ----
     edge_src: jax.Array         # (B, Ep) int32 — local slot, or Vp + halo slot
     edge_dst: jax.Array         # (B, Ep) int32 — destination local slot
@@ -639,6 +642,7 @@ def _finalize_graph(arrs: dict[str, np.ndarray],
     each host copy as soon as it is converted (the out-of-core path's peak
     memory is the final structure, not twice it)."""
     vertex_mask = arrs["vertex_gid"] >= 0
+    local_out_degree = _local_out_degree(arrs, n_partitions, vp, layout)
 
     def take(name: str):
         return jnp.asarray(arrs.pop(name))
@@ -646,6 +650,7 @@ def _finalize_graph(arrs: dict[str, np.ndarray],
     return PartitionedGraph(
         vertex_gid=take("vertex_gid"), vertex_mask=jnp.asarray(vertex_mask),
         is_boundary=take("is_boundary"), out_degree=take("out_degree"),
+        local_out_degree=jnp.asarray(local_out_degree),
         edge_src=take("edge_src"), edge_dst=take("edge_dst"),
         edge_w=take("edge_w"), edge_mask=take("edge_mask"),
         edge_local=take("edge_local"),
@@ -662,6 +667,20 @@ def _finalize_graph(arrs: dict[str, np.ndarray],
         n_blocks=layout.n_blocks, ep_by_p=layout.ep_by_p,
         gp_by_p=layout.gp_by_p,
     )
+
+
+def _local_out_degree(arrs: dict[str, np.ndarray], n_partitions: int,
+                      vp: int, layout: _EdgeLayout) -> np.ndarray:
+    """(P, Vp) count of each vertex's out-edges into its own partition,
+    read off the filled edge family one block row at a time: each local
+    edge is one slot of the local ELL layout, whose source it is."""
+    deg = np.zeros(n_partitions * vp, np.int64)
+    for b in range(layout.n_blocks):
+        sel = np.logical_and(arrs["edge_mask"][b], arrs["edge_local"][b])
+        part = b * layout.ppb + arrs["edge_part"][b][sel].astype(np.int64)
+        deg += np.bincount(part * vp + arrs["edge_src"][b][sel],
+                           minlength=deg.size)
+    return deg.astype(np.int32).reshape(n_partitions, vp)
 
 
 def _ell_pick(e: dict[str, np.ndarray], negate: bool) -> dict[str, np.ndarray]:

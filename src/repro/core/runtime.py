@@ -280,9 +280,9 @@ def ell_send_accounting(graph: PartitionedGraph, slices, views, send_flat,
                         p: int):
     """Exact parity with the dense local accounting, from the ELL layout:
     per-destination has-flags (one combined local group per messaged dst)
-    and the raw in-memory message count (every valid sender edge slot).
-    The single source of truth for both `deliver`'s kernel path and the
-    fused local phases."""
+    and the raw in-memory message count (every valid sender edge slot),
+    for `deliver`'s kernel path.  The fused local phases count without
+    it, from ``local_out_degree`` (``exec/local_phase.py:_count_local``)."""
     has = jnp.zeros((p * graph.vp,), bool)
     mem = jnp.zeros((), jnp.int32)
     for s, (rows, idx, msk) in zip(slices, views):

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.core.graph import PartitionedGraph
 from repro.core.runtime import (EngineState, _has_any_pending, apply_phase,
-                                deliver, ell_send_accounting)
+                                deliver)
 from repro.core.vertex_program import StepInfo, VertexProgram
 
 __all__ = ["local_phase", "fused_step_fn", "participation_mask",
@@ -140,7 +140,21 @@ def fused_step_fn(graph: PartitionedGraph, prog: VertexProgram, kind: str,
             return unflat(xn), unflat(d), unflat(s)
     else:  # pragma: no cover
         raise ValueError(kind)
-    return step, slices, views
+    return step
+
+
+def _count_local(graph: PartitionedGraph, send, has, metrics):
+    """A fused pseudo-superstep's message counters, equal to the dense
+    accounting's without reading an ELL slot: one combined local message
+    per destination with a message (``has``, from the kernel's delivered
+    values), and one raw in-memory message per local out-edge of each
+    vertex-level sender (``send``; a K-lane message counts once)."""
+    net_local, mem = metrics
+    with jax.named_scope("message_accounting"):
+        net_local = net_local + jnp.sum(has).astype(jnp.int32)
+        mem = mem + jnp.sum(jnp.where(send, graph.local_out_degree, 0)
+                            ).astype(jnp.int32)
+    return net_local, mem
 
 
 def _fused_pr_local_phase(
@@ -171,7 +185,7 @@ def _fused_pr_local_phase(
     """
     p = es.send.shape[0]
     ch = prog.channels[0]
-    kstep, slices, views = fused_step_fn(graph, prog, "pr_step", p)
+    kstep = fused_step_fn(graph, prog, "pr_step", p)
     tol = prog.tol
     name = ch.name
     # lane channels: send flags ride the loop per-lane (the kernel's SpMM
@@ -209,18 +223,9 @@ def _fused_pr_local_phase(
         # final fused apply back to generic-path semantics (see below)
         prev = (rank, out_d, eo, esend, send)
         rank_n, d_in, send_n = kstep(rank, delta, send)
-        net_local, mem = metrics
+        has_n = vany(d_in > 0)     # positive-contribution invariant
         if collect_metrics:
-            # exact parity with the dense accounting: has-flags from the
-            # send gather, one combined local group per messaged dst (a
-            # K-lane message counts once — vertex-level send)
-            has_n, mem_inc = ell_send_accounting(graph, slices, views,
-                                                 vany(send).reshape(-1), p)
-            with jax.named_scope("message_accounting"):
-                net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
-                mem = mem + mem_inc
-        else:
-            has_n = vany(d_in > 0)     # positive-contribution invariant
+            metrics = _count_local(graph, vany(send), has_n, metrics)
         if lanes:
             out_d = jnp.where(ex(has_n), jnp.where(send_n, d_in, 0.0), out_d)
         else:
@@ -230,7 +235,7 @@ def _fused_pr_local_phase(
         running = jnp.any(has_n, axis=1)
         pseudo = pseudo + running.astype(jnp.int32)
         return (rank_n, d_in, send_n, has_n, out_d, eo, esend, running,
-                pseudo, (net_local, mem), k + 1, prev)
+                pseudo, metrics, k + 1, prev)
 
     carry0 = (rank, p0, send, has0, out_delta, exp_out, exp_send, running0,
               c0.pseudo_supersteps,
@@ -302,7 +307,7 @@ def _fused_min_local_phase(
     combine, _, sr_ident = SEMIRINGS[ch.semiring]
     improves = semiring_improves(ch.semiring)
     p = es.send.shape[0]
-    kstep, slices, views = fused_step_fn(graph, prog, "min_step", p)
+    kstep = fused_step_fn(graph, prog, "min_step", p)
     vmask = graph.vertex_mask
     # lane channels: per-lane send flags ride the loop (SpMM gating in the
     # kernel); `vany` collapses to the vertex level for scheduling/export
@@ -335,22 +340,16 @@ def _fused_min_local_phase(
         # pre-step apply state for the max_local_steps cutoff rollback
         prev = (x, eo, esend, send)
         x_n, d_n, send_n = kstep(x, send)
-        net_local, mem = metrics
+        # some sender beat the identity (any lane)
+        has_n = vany(improves(d_n, sr_ident))
         if collect_metrics:
-            has_n, mem_inc = ell_send_accounting(graph, slices, views,
-                                                 vany(send).reshape(-1), p)
-            with jax.named_scope("message_accounting"):
-                net_local = net_local + jnp.sum(has_n).astype(jnp.int32)
-                mem = mem + mem_inc
-        else:
-            # some sender beat the identity (any lane)
-            has_n = vany(improves(d_n, sr_ident))
+            metrics = _count_local(graph, vany(send), has_n, metrics)
         eo = jnp.where(ex(vany(send_n)), x_n, eo)
         esend = jnp.logical_or(esend, vany(send_n))
         running = jnp.any(has_n, axis=1)
         pseudo = pseudo + running.astype(jnp.int32)
         return (x_n, d_n, send_n, has_n, eo, esend, running, pseudo,
-                (net_local, mem), k + 1, prev)
+                metrics, k + 1, prev)
 
     carry0 = (x1, m0f, send1, has0, eo_f, esend1, running0,
               c0.pseudo_supersteps,

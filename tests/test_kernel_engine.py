@@ -5,6 +5,9 @@ reach the same fixed point as the dense gather/segment path — bit-for-bit
 for min/lexmin combiners, to float-reassociation tolerance for 'sum' — with
 identical iteration counts and paper counters."""
 
+import importlib
+import re
+
 import numpy as np
 import pytest
 
@@ -13,7 +16,8 @@ import jax.numpy as jnp
 from repro.core import (bfs_partition, build_partitioned_graph,
                         hash_partition, run_am, run_bsp, run_hybrid)
 from repro.core.apps import (SSSP, WCC, BipartiteMatching,
-                             IncrementalPageRank, RandomWalk, WidestPath)
+                             IncrementalPageRank, MultiSourceMonotone,
+                             RandomWalk, WidestPath)
 from repro.core.apps.pagerank import pagerank_edge_weights
 from repro.core.apps.random_walk import random_walk_edge_weights
 from repro.core.runtime import ell_channels
@@ -21,6 +25,9 @@ from repro.data.graphs import bipartite_graph, grid_graph, rmat_graph, symmetriz
 
 from test_delivery_parity import assert_remote_delivery_matches as \
     _assert_remote_delivery_matches
+
+# the module: ``repro.exec.local_phase`` names the function it exports
+local_phase_mod = importlib.import_module("repro.exec.local_phase")
 
 RUNNERS = {"bsp": run_bsp, "am": run_am, "hybrid": run_hybrid}
 ENGINES = ["bsp", "am", "hybrid"]
@@ -87,16 +94,22 @@ def test_graph_carries_ell_layout(road):
     assert bool(jnp.all(jnp.where(rbase.msk, rbase.idx >= graph.vp, True)))
 
 
-def test_skewed_graph_keeps_fast_path_with_bins():
-    """Power-law in-degree no longer bails out to dense: hub rows spill into
-    extra ELL bins and every engine still reaches the dense fixed point with
-    identical counters."""
+def _skewed_edges():
+    """An R-MAT graph plus 2,000 arcs into five hubs, whose rows spill past
+    the local ELL base bin of width 16."""
     edges, n = rmat_graph(600, avg_degree=10, seed=11)
     rng = np.random.RandomState(5)
     hubs = np.stack([rng.randint(0, n, size=2000),
                      rng.randint(0, 5, size=2000)], axis=1)
     edges = np.unique(np.concatenate([edges, hubs]), axis=0)
-    edges = edges[edges[:, 0] != edges[:, 1]]
+    return edges[edges[:, 0] != edges[:, 1]], n
+
+
+def test_skewed_graph_keeps_fast_path_with_bins():
+    """Power-law in-degree no longer bails out to dense: hub rows spill into
+    extra ELL bins and every engine still reaches the dense fixed point with
+    identical counters."""
+    edges, n = _skewed_edges()
     part = hash_partition(n, 4, seed=0)
     w = pagerank_edge_weights(edges, n)
     graph = build_partitioned_graph(edges, n, part, weights=w,
@@ -422,3 +435,142 @@ def test_fused_min_cutoff_parity(road):
         np.testing.assert_array_equal(unpack(graph, es_d, "dist"),
                                       unpack(graph, es_k, "dist"))
         assert_counters_equal(es_d, es_k)
+
+
+def local_ell_slot_degrees(graph):
+    """(P, Vp) count of the valid local-ELL slots, over every bin, whose
+    source is each vertex."""
+    size = graph.n_partitions * graph.vp
+    deg = sum(np.bincount(np.asarray(s.flat_idx)[np.asarray(s.msk).reshape(
+        -1, s.kb)], minlength=size) for s in graph.local_ell)
+    return deg.reshape(graph.n_partitions, graph.vp)
+
+
+@pytest.mark.parametrize("source", ["built", "ghp"])
+def test_local_out_degree_counts_local_ell_slots(tmp_path, source):
+    """The build-time slot degrees the fused local phases count messages
+    with: in memory and through the out-of-core ``.ghp`` loader alike."""
+    from repro.io import build_partitioned_graph_from_path, save_graph
+
+    edges, n = _skewed_edges()
+    part = hash_partition(n, 4, seed=0)
+    if source == "built":
+        graph = build_partitioned_graph(edges, n, part, ell_base_slices=16)
+    else:
+        path = str(tmp_path / "g.ghp")
+        save_graph(path, edges, n, part)
+        graph = build_partitioned_graph_from_path(path, chunk_edges=512,
+                                                  ell_base_slices=16)
+    assert len(graph.local_ell) > 1
+    deg = np.asarray(graph.local_out_degree)
+    assert deg.dtype == np.int32
+    np.testing.assert_array_equal(deg, local_ell_slot_degrees(graph))
+    local = part[edges[:, 0]] == part[edges[:, 1]]
+    assert deg.sum() == local.sum() > 0
+
+
+# (weights, program factory, vdata, fused kernel) of each fused local phase
+_FUSED = {
+    "sssp": ("uniform", lambda: SSSP(source=0), None, "min_step"),
+    "wcc": (None, WCC, None, "min_step"),
+    "widest_path": ("uniform", lambda: WidestPath(source=0), None,
+                    "min_step"),
+    "random_walk_min_mul": ("odds", lambda: RandomWalk(source=0, mode="odds"),
+                            None, "min_step"),
+    "pagerank": ("pagerank", lambda: IncrementalPageRank(tolerance=1e-4),
+                 None, "pr_step"),
+    "sssp_3_lanes": ("uniform",
+                     lambda: MultiSourceMonotone(lanes=3, semiring="min_add"),
+                     {"sources": jnp.asarray([0, 1, 2], jnp.int32)},
+                     "min_step"),
+}
+
+
+def _skewed_weights(kind, edges, n):
+    if kind == "uniform":
+        return np.random.RandomState(2).uniform(
+            0.1, 5.0, len(edges)).astype(np.float32)
+    if kind == "pagerank":
+        return pagerank_edge_weights(edges, n)
+    return None if kind is None else random_walk_edge_weights(edges, n, kind)
+
+
+def _outcome(es, lanes: bool):
+    """(state, pending payload, pending flags) of a single-channel run.  A
+    K-lane payload is read as the state it ⊕-combines into: the generic
+    path's inbox holds a lane's non-improving value where the fused
+    kernel, gating each lane, holds the identity."""
+    (name, ((payload,), has)), = es.pending.items()
+    state = es.state[next(iter(es.state))]
+    if lanes:
+        payload = jnp.minimum(state, payload)      # the min_add lanes' ⊕
+    return np.asarray(state), np.asarray(payload), np.asarray(has)
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED))
+def test_fused_local_phase_counts_equal_generic_path(monkeypatch, case):
+    """With the counters on, each fused local phase gives the state, the
+    pending messages and every counter of the generic local phase on the
+    same ELL layout: to its fixed point, and cut mid-run with messages
+    still pending.  The graph spills past the base bin, so the slot
+    degrees have to count every bin.  Bit for bit, but PageRank's 'sum'
+    values, which the two paths add in different orders."""
+    kind, make, vdata, kern = _FUSED[case]
+    edges, n = _skewed_edges()
+    graph = build_partitioned_graph(edges, n, hash_partition(n, 4, seed=0),
+                                    weights=_skewed_weights(kind, edges, n),
+                                    ell_base_slices=16)
+    assert len(graph.local_ell) > 1
+    assert local_phase_mod.fused_local_kernel(graph, make(), True,
+                                              100_000) == kern
+
+    def run(generic, **kw):
+        with monkeypatch.context() as m:
+            if generic:
+                m.setattr(local_phase_mod, "fused_local_kernel",
+                          lambda *a: None)
+            return run_hybrid(graph, make(), vdata=vdata, **kw)
+
+    lanes = vdata is not None
+    for kw in ({}, {"max_iters": 2, "max_local_steps": 1}):
+        (es_f, it_f), (es_g, it_g) = run(False, **kw), run(True, **kw)
+        assert it_f == it_g, kw
+        assert_counters_equal(es_f, es_g)
+        assert int(es_f.counters.mem_messages) > 0
+        (x_f, m_f, has_f), (x_g, m_g, has_g) = (_outcome(es_f, lanes),
+                                                _outcome(es_g, lanes))
+        np.testing.assert_array_equal(has_f, has_g, err_msg=str(kw))
+        if kind == "pagerank":
+            np.testing.assert_allclose(x_f, x_g, rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(m_f, m_g, rtol=1e-5, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(x_f, x_g, err_msg=str(kw))
+            np.testing.assert_array_equal(m_f, m_g, err_msg=str(kw))
+    assert bool(has_f.any()), "the cut run should leave messages pending"
+
+
+def test_fused_local_phase_loop_reads_no_send_flag_slot():
+    """The Graph500 cell's device loop at scale 10, counters on: inside the
+    local phase's loop body the only gathers are the values' float32
+    gathers, one per local ELL bin, and nothing boolean is gathered or
+    scattered.  The fused local phase counts its messages from the slot
+    degrees, not from a gather of the send flags over every slot."""
+    from bench.gen.kronecker import kronecker_edges
+    from repro.exec import driver
+    from repro.exec.policy import hybrid_policy
+
+    edges, w, n = kronecker_edges(10, 16, [0.57, 0.19, 0.19, 0.05], 1)
+    graph = build_partitioned_graph(edges, n, hash_partition(n, 8, seed=3),
+                                    weights=w, ell_base_slices=16)
+    assert len(graph.local_ell) > 1
+    prog = MultiSourceMonotone(lanes=1, semiring="min_add")
+    loop, _ = driver.device_loop_jit(prog, hybrid_policy(), 100_000, True)
+    hlo = loop.lower(graph, {"sources": jnp.asarray([0], jnp.int32)},
+                     None).compile().as_text()
+    ops = {"gather": [], "scatter": []}
+    for line in hlo.splitlines():
+        op = re.search(r"= (\w+)\[[^\]]*\]\S* (gather|scatter)\(", line)
+        if op and "local_phase/while/body/" in line:
+            ops[op.group(2)].append(op.group(1))
+    assert ops["gather"] == ["f32"] * len(graph.local_ell), ops
+    assert "pred" not in ops["scatter"], ops

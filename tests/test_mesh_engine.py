@@ -131,6 +131,20 @@ def profiled(fn):
 out = {"mesh_found": driver.graph_mesh(placed) is mesh,
        "one_device_mesh": driver.graph_mesh(graph) is None}
 
+# the slot degrees over the mesh: each device holds its partitions' rows of
+# the count of valid local-ELL slots, over every bin, by source
+size = graph.n_partitions * graph.vp
+want = sum(np.bincount(np.asarray(s.flat_idx)[np.asarray(s.msk).reshape(
+    -1, s.kb)], minlength=size) for s in graph.local_ell).reshape(
+    graph.n_partitions, graph.vp)
+deg = placed.local_out_degree
+out["local_out_degree"] = {
+    "devices": len(deg.sharding.device_set),
+    "rows": sorted(int(x.data.shape[0]) for x in deg.addressable_shards),
+    "equal": all(np.array_equal(np.asarray(x.data), want[x.index])
+                 for x in deg.addressable_shards)
+    and np.array_equal(np.asarray(deg), want) and int(want.sum()) > 0}
+
 # bit for bit: SSSP on the ELL and the dense path, and WCC
 cases = {"sssp-ell": (lambda: MultiSourceMonotone(lanes=1,
                                                   semiring="min_add"),
@@ -218,6 +232,14 @@ def test_mesh_run_equals_one_device_bit_for_bit(mesh_run, case):
     assert one["counters"] == mesh["counters"]
     assert one["counters"]["net_messages"] > 0
     assert one["devices"] == [1] and mesh["devices"] == [4]
+
+
+def test_mesh_devices_hold_their_partitions_slot_degrees(mesh_run):
+    """The graph's local out-degree leaf, which the fused local phase
+    counts messages with, splits on dim 0: two partitions' rows a device,
+    each equal to its partitions' local-ELL slot counts."""
+    assert mesh_run["local_out_degree"] == {"devices": 4, "rows": [2] * 4,
+                                            "equal": True}
 
 
 def test_mesh_run_from_a_seeded_state_equals_one_device(mesh_run):
